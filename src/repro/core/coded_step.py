@@ -17,16 +17,14 @@ assignment + weights each epoch.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, NamedTuple, Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.coding import CodingScheme, decode_weights
-
 __all__ = ["SlotPlan", "build_slot_plan", "slot_weights",
-           "make_train_step", "make_coded_train_step"]
+           "make_train_step", "coded_value_and_grad", "make_coded_train_step"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,19 +99,30 @@ def make_train_step(loss_fn: Callable, optimizer, *,
     return step
 
 
-def make_coded_train_step(per_slot_loss_fn: Callable, optimizer) -> Callable:
-    """Coded step over slotted batches.
+def coded_value_and_grad(per_slot_loss_fn: Callable) -> Callable:
+    """(params, slot_batch, weights) -> (weighted loss, decoded gradient).
 
     ``per_slot_loss_fn(params, slot_batch) -> (M, n_slots)`` per-slot mean
-    losses.  The step contracts them with the runtime-supplied weight matrix
-    (a_m·B[m,k]) — by linearity the resulting gradient is the exact decoded
-    full gradient.
+    losses.  Contracting them with the runtime-supplied weight matrix
+    (a_m·B[m,k]) makes the gradient, by linearity, the exact decoded full
+    gradient Σ_k g_k.
     """
-    def step(params, opt_state, slot_batch, weights):
+    def fn(params, slot_batch, weights):
         def total_loss(p):
             per_slot = per_slot_loss_fn(p, slot_batch)       # (M, n_slots)
             return jnp.sum(per_slot * weights)
-        loss, grads = jax.value_and_grad(total_loss)(params)
+        return jax.value_and_grad(total_loss)(params)
+
+    return fn
+
+
+def make_coded_train_step(per_slot_loss_fn: Callable, optimizer) -> Callable:
+    """Coded step over slotted batches: the :func:`coded_value_and_grad`
+    gradient, then one optimizer update."""
+    value_and_grad = coded_value_and_grad(per_slot_loss_fn)
+
+    def step(params, opt_state, slot_batch, weights):
+        loss, grads = value_and_grad(params, slot_batch, weights)
         params, opt_state = optimizer.update(grads, opt_state, params)
         return params, opt_state, {"loss": loss}
 

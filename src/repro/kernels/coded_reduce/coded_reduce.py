@@ -18,14 +18,10 @@ weighted sum.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels._compat import tpu_compiler_params
 
 __all__ = ["coded_reduce_kernel", "coded_reduce_pallas"]
 
@@ -35,11 +31,12 @@ def coded_reduce_kernel(g_ref, w_ref, o_ref):
     w = w_ref[...].astype(jnp.float32)          # (n_slots, 1)
     o_ref[...] = jax.lax.dot_general(
         w, g, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,    # an exact f32 decode
         preferred_element_type=jnp.float32)     # (1, Bd)
 
 
 def coded_reduce_pallas(g, w, *, block_d: int = 512,
-                        interpret: bool = True):
+                        interpret: bool = False):
     """g: (n_slots, D); w: (n_slots,) -> (D,) f32."""
     n_slots, D = g.shape
     block_d = min(block_d, D)
@@ -56,7 +53,7 @@ def coded_reduce_pallas(g, w, *, block_d: int = 512,
         ],
         out_specs=pl.BlockSpec((1, block_d), lambda di: (0, di)),
         out_shape=jax.ShapeDtypeStruct((1, Dp), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(g, w.reshape(n_slots, 1))

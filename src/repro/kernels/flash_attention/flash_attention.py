@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
-
 __all__ = ["flash_attention_kernel", "flash_attention_pallas"]
 
 _NEG_INF = -1e30
@@ -87,7 +85,7 @@ def flash_attention_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
                            scale=None, block_q: int = 128,
-                           block_k: int = 128, interpret: bool = True):
+                           block_k: int = 128, interpret: bool = False):
     """q,k,v: (B, H, S, D) -> (B, H, S, D)."""
     B, H, S, D = q.shape
     scale = scale if scale is not None else D ** -0.5
@@ -117,7 +115,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)

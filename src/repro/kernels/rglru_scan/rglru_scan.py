@@ -5,9 +5,13 @@ sequential over S).  HBM→VMEM traffic is the bottleneck (element-wise VPU
 work), so the kernel streams (Bs, Bd) tiles and keeps the carry h in VMEM:
 
   grid = (B, D/Bd, S/Bs)  — seq innermost ('arbitrary'), batch/channel
-  'parallel'.  Within a tile the scan is computed by the log-depth
-  Blelloch-style combine (jnp ops lower to VPU), then the carried h is
-  applied via the tile's cumulative decay A_t = Π a and the carry updated:
+  'parallel'.  Within a tile the scan is a log-depth Hillis–Steele combine:
+  step d rolls the running (A, h) pairs down d rows along the sublane axis
+  (``pltpu.roll``, an XLU rotate) and folds each row with the one d rows
+  above it, masking the rows that wrapped around.  Everything stays in
+  full (Bs, Bd) vregs — no strided or zero-length slices, which Mosaic
+  refuses.  The carried h is then applied via the tile's cumulative decay
+  A_t = Π a and the carry updated:
       h_t(tile) = scan(a, b)_t + A_t ⊙ h_in.
 """
 from __future__ import annotations
@@ -18,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels._compat import tpu_compiler_params
 
 __all__ = ["rglru_scan_kernel", "rglru_scan_pallas"]
 
@@ -32,15 +34,18 @@ def rglru_scan_kernel(a_ref, b_ref, o_ref, hlast_ref, h_ref, *,
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    a = a_ref[0].astype(jnp.float32)          # (Bs, Bd)
-    b = b_ref[0].astype(jnp.float32)
-
-    def combine(u, v):
-        a1, b1 = u
-        a2, b2 = v
-        return a1 * a2, a2 * b1 + b2
-
-    A, inner = jax.lax.associative_scan(combine, (a, b), axis=0)
+    A = a_ref[0].astype(jnp.float32)          # (Bs, Bd)
+    inner = b_ref[0].astype(jnp.float32)
+    row = jax.lax.broadcasted_iota(jnp.int32, A.shape, 0)
+    d = 1
+    while d < A.shape[0]:
+        # row t takes (A, h) of row t-d, the identity (1, 0) where t < d
+        late = row >= d
+        A_up = jnp.where(late, pltpu.roll(A, d, 0), 1.0)
+        h_up = jnp.where(late, pltpu.roll(inner, d, 0), 0.0)
+        inner = A * h_up + inner
+        A = A * A_up
+        d *= 2
     h_in = h_ref[...]                          # (1, Bd)
     out = inner + A * h_in
     o_ref[0] = out.astype(o_ref.dtype)
@@ -52,7 +57,7 @@ def rglru_scan_kernel(a_ref, b_ref, o_ref, hlast_ref, h_ref, *,
 
 
 def rglru_scan_pallas(a, b, *, block_s: int = 256, block_d: int = 128,
-                      interpret: bool = True):
+                      interpret: bool = False):
     """a, b: (B, S, D) -> (out (B,S,D), h_last (B,D))."""
     B, S, D = a.shape
     block_s = min(block_s, S)
@@ -79,7 +84,7 @@ def rglru_scan_pallas(a, b, *, block_s: int = 256, block_d: int = 128,
             jax.ShapeDtypeStruct((B, 1, D), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
-
 __all__ = ["wkv_kernel", "wkv_pallas"]
 
 
@@ -41,7 +39,14 @@ def wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, slast_ref, s_ref,
     u = u_ref[0].astype(jnp.float32)            # (1, K)
 
     lw = jnp.log(jnp.maximum(w, 1e-38))
-    la = jnp.cumsum(lw, axis=0)                 # (C, K)
+    # inclusive prefix sum over the chunk as a lower-triangular ones matmul
+    # (Mosaic has no cumsum); fp32 contraction keeps the log-decays exact
+    tril = (jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >=
+            jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1))
+    la = jax.lax.dot_general(tril.astype(jnp.float32), lw,
+                             (((1,), (0,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
+                             preferred_element_type=jnp.float32)  # (C, K)
 
     # intra-chunk scores: A[t,s] = Σ_k r[t,k]·k[s,k]·exp(la[t-1,k]-la[s,k])
     q_t = r * jnp.exp(la - lw)                  # r_t e^{la[t-1]}  (≤ |r|)
@@ -73,7 +78,7 @@ def wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, slast_ref, s_ref,
         slast_ref[0] = S_new.astype(slast_ref.dtype)
 
 
-def wkv_pallas(r, k, v, w, u, *, chunk: int = 32, interpret: bool = True):
+def wkv_pallas(r, k, v, w, u, *, chunk: int = 32, interpret: bool = False):
     """r/k/w: (B,H,S,K); v: (B,H,S,V); u: (H,K) -> (out (B,H,S,V), S_last)."""
     B, H, S, K = r.shape
     V = v.shape[-1]
@@ -106,7 +111,7 @@ def wkv_pallas(r, k, v, w, u, *, chunk: int = 32, interpret: bool = True):
             jax.ShapeDtypeStruct((B * H, K, V), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rf, kf, vf, wf, uf)

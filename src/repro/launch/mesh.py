@@ -8,21 +8,8 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["abstract_mesh", "make_production_mesh", "make_mesh_from_str",
-           "batch_axes", "data_shards", "fleet_mesh"]
-
-
-def abstract_mesh(axis_sizes: tuple, axis_names: tuple):
-    """Version-compat ``AbstractMesh`` constructor.
-
-    jax <= 0.4.x takes a single ``((name, size), ...)`` shape tuple;
-    jax >= 0.5 takes ``(axis_sizes, axis_names)``.  Device-free either way.
-    """
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+__all__ = ["make_production_mesh", "make_mesh_from_str", "batch_axes",
+           "data_shards", "fleet_mesh"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -36,7 +23,6 @@ def make_mesh_from_str(spec: str):
     over the same 256 chips (experts resident per model column, §Perf)."""
     dims = tuple(int(x) for x in spec.split("x"))
     axes = {2: ("data", "model"), 3: ("pod", "data", "model")}[len(dims)]
-    import jax
     return jax.make_mesh(dims, axes)
 
 

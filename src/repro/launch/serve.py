@@ -20,6 +20,7 @@ import numpy as np
 from repro.configs.base import get_config
 from repro.core.lyapunov import (Observation, SystemParams, init_queues,
                                  jain_index, schedule_slot)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import TINY
 from repro.models import transformer as tfm
 
@@ -38,6 +39,7 @@ def main(argv=None):
 
     cfg = TINY if args.arch == "tiny" else get_config(args.arch,
                                                       reduced=True)
+    enable_compile_cache()
     params = tfm.init_params(cfg, jax.random.PRNGKey(0))
     Mc = args.clients
     rng = np.random.default_rng(0)

@@ -2,20 +2,24 @@
 
 Runs the full stack: synthetic partitioned data pipeline → (optionally)
 two-stage coded gradient runtime → train step → checkpointing/resume.
-On this CPU container the models are the reduced configs (or the ~100M
-``--preset 100m``); on a pod the same driver runs the full configs under
-the production mesh (the dry-run proves those compile).
+``--reduced`` (the default) picks an architecture's small config,
+``--no-reduced`` its published one, and ``--layers`` cuts depth.  The coded
+path donates params and optimizer state to its step, so a model whose
+state alone takes half the device's memory still fits.
 
 Examples:
   python -m repro.launch.train --arch tiny --steps 50
   python -m repro.launch.train --arch qwen3-14b --reduced --steps 20 --coded
-  python -m repro.launch.train --preset 100m --steps 300 --ckpt-dir /tmp/ck
+  python -m repro.launch.train --arch stablelm-1.6b --no-reduced --layers 4 \
+      --coded --batch 1 --seq 256 --slots 5 --steps 5
+  python -m repro.launch.train --preset 100m --steps 300 --ckpt-dir ck
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
 import time
+from typing import Iterator
 
 import jax
 import jax.numpy as jnp
@@ -24,8 +28,9 @@ import numpy as np
 from repro.checkpoint import Checkpointer
 from repro.configs.base import ModelConfig, get_config
 from repro.core.coded_step import make_coded_train_step, make_train_step
-from repro.core.runtime import TwoStageRuntime
+from repro.core.runtime import EpochResult, TwoStageRuntime
 from repro.data.pipeline import SyntheticLMDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tfm
 from repro.optim import adamw
 
@@ -39,10 +44,14 @@ PRESET_100M = ModelConfig(name="preset-100m", family="dense", n_layers=12,
 
 def _config(args) -> ModelConfig:
     if args.preset == "100m":
-        return PRESET_100M
-    if args.arch == "tiny":
-        return TINY
-    return get_config(args.arch, reduced=args.reduced)
+        cfg = PRESET_100M
+    elif args.arch == "tiny":
+        cfg = TINY
+    else:
+        cfg = get_config(args.arch, reduced=args.reduced)
+    if args.layers:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
+    return cfg
 
 
 def per_slot_lm_loss(cfg: ModelConfig):
@@ -68,11 +77,85 @@ def per_slot_lm_loss(cfg: ModelConfig):
     return fn
 
 
+def coded_runtime(workers: int, *, straggler_prob: float = 0.2,
+                  n_slots: int = 0, seed: int = 0) -> TwoStageRuntime:
+    """The coded path's epoch planner: ``workers`` heterogeneous simulated
+    workers (rates 1..4) over K = 2·workers data partitions.  ``n_slots``
+    pins the slot width so every epoch shares one step compile (0 sizes
+    each epoch to its plan)."""
+    return TwoStageRuntime(workers, 2 * workers, max(workers // 2, 2),
+                           rates=np.linspace(1.0, 4.0, workers),
+                           straggler_prob=straggler_prob, n_slots=n_slots,
+                           seed=seed)
+
+
+def slot_batch(ds, plan, step: int) -> dict:
+    """Stack the epoch's partitions into the plan's (M, n_slots, b, S)
+    layout; unused slots (partition -1) get zeros, and zero weight."""
+    used = plan.slot_partition[plan.slot_partition >= 0]
+    parts = {int(k): {key: np.asarray(v)
+                      for key, v in ds.partition(step, int(k)).items()}
+             for k in np.unique(used)}
+    sample = next(iter(parts.values()))
+    empty = {key: np.zeros_like(v) for key, v in sample.items()}
+    return {key: jnp.asarray(np.stack([
+        np.stack([parts[int(k)][key] if k >= 0 else empty[key]
+                  for k in row]) for row in plan.slot_partition]))
+        for key in sample}
+
+
+@dataclasses.dataclass
+class CodedStep:
+    """One coded training step: its loss, its device time (dispatch to
+    ``block_until_ready``; the first step's includes compilation) and the
+    runtime's epoch result (plan, weights, simulated epoch time)."""
+    step: int
+    loss: float
+    seconds: float
+    epoch: EpochResult
+
+
+def train_coded(cfg: ModelConfig, opt, params, opt_state, *, steps: int,
+                batch: int, seq: int, workers: int = 6,
+                straggler_prob: float = 0.2, n_slots: int = 0,
+                start_step: int = 0, ckpt=None, ckpt_every: int = 25
+                ) -> Iterator[CodedStep]:
+    """Run the two-stage coded loop, yielding one :class:`CodedStep` per
+    step.  ``params``/``opt_state`` are donated to the first step: the
+    caller's arrays are consumed."""
+    runtime = coded_runtime(workers, straggler_prob=straggler_prob,
+                            n_slots=n_slots)
+    ds = SyntheticLMDataset(runtime.K, examples_per_partition=batch,
+                            seq_len=seq, vocab=cfg.vocab)
+    # params and optimizer state are donated: the update writes over them
+    step_fn = jax.jit(make_coded_train_step(per_slot_lm_loss(cfg), opt),
+                      donate_argnums=(0, 1))
+    for step in range(start_step, steps):
+        res = runtime.run_epoch(step)
+        sb = slot_batch(ds, res.plan, step)
+        w = jnp.asarray(res.weights, jnp.float32)
+        t0 = time.perf_counter()
+        params, opt_state, aux = jax.block_until_ready(
+            step_fn(params, opt_state, sb, w))
+        dt = time.perf_counter() - t0
+        if ckpt and step and step % ckpt_every == 0:
+            ckpt.async_save(step, {"params": params, "opt": opt_state})
+        yield CodedStep(step=step, loss=float(aux["loss"]), seconds=dt,
+                        epoch=res)
+    if ckpt:
+        ckpt.wait()
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tiny")
     ap.add_argument("--preset", default=None)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="the architecture's small config (--no-reduced: "
+                         "its published widths)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the config to this many layers (0 = keep)")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -81,6 +164,8 @@ def main(argv=None):
                     help="two-stage coded gradient runtime (simulated "
                          "heterogeneous workers)")
     ap.add_argument("--workers", type=int, default=6)
+    ap.add_argument("--slots", type=int, default=0,
+                    help="coded slot width per worker (0 = per epoch)")
     ap.add_argument("--straggler-prob", type=float, default=0.2)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=25)
@@ -91,6 +176,7 @@ def main(argv=None):
     if cfg.family in ("vlm", "audio"):
         raise SystemExit("train driver covers LM families; use the smoke "
                          "tests for frontend-stub archs")
+    enable_compile_cache()
     opt = adamw(lr=args.lr, state_dtype=cfg.opt_state_dtype)
     params = tfm.init_params(cfg, jax.random.PRNGKey(0))
     n_params = sum(p.size for p in jax.tree.leaves(params))
@@ -98,61 +184,28 @@ def main(argv=None):
           f"coded={args.coded} steps={args.steps}")
 
     start_step = 0
+    opt_state = opt.init(params)
     ck = Checkpointer(args.ckpt_dir) if args.ckpt_dir else None
+    if ck and ck.latest_step() is not None:
+        start_step, t = ck.restore({"params": params, "opt": opt_state})
+        params, opt_state = t["params"], t["opt"]
+        print(f"resumed from step {start_step}")
+    t0 = time.time()
 
     if args.coded:
-        M = args.workers
-        K = M * 2
-        ds = SyntheticLMDataset(K, examples_per_partition=args.batch,
-                                seq_len=args.seq, vocab=cfg.vocab)
-        runtime = TwoStageRuntime(M, K, max(M // 2, 2),
-                                  rates=np.linspace(1.0, 4.0, M),
-                                  straggler_prob=args.straggler_prob,
-                                  seed=0)
-        step_fn = jax.jit(make_coded_train_step(per_slot_lm_loss(cfg), opt))
-        opt_state = opt.init(params)
-        if ck and ck.latest_step() is not None:
-            start_step, t = ck.restore({"params": params, "opt": opt_state})
-            params, opt_state = t["params"], t["opt"]
-            print(f"resumed from step {start_step}")
-        t0 = time.time()
-        for step in range(start_step, args.steps):
-            res = runtime.run_epoch(step)
-            plan = res.plan
-            # build slot batch
-            zeros = None
-            batches = {}
-            for m in range(plan.M):
-                for s in range(plan.n_slots):
-                    k = int(plan.slot_partition[m, s])
-                    part = ds.partition(step, k) if k >= 0 else None
-                    batches[(m, s)] = part
-            sample = next(p for p in batches.values() if p is not None)
-            slot_batch = {key: [] for key in sample}
-            for m in range(plan.M):
-                rows = {key: [] for key in sample}
-                for s in range(plan.n_slots):
-                    src = batches[(m, s)]
-                    for key in sample:
-                        rows[key].append(np.asarray(
-                            src[key] if src is not None
-                            else np.zeros_like(np.asarray(sample[key]))))
-                for key in sample:
-                    slot_batch[key].append(np.stack(rows[key]))
-            slot_batch = {k: jnp.asarray(np.stack(v))
-                          for k, v in slot_batch.items()}
-            params, opt_state, aux = step_fn(
-                params, opt_state, slot_batch,
-                jnp.asarray(res.weights, jnp.float32))
-            if step % args.log_every == 0:
-                print(f"step {step:4d} loss={float(aux['loss']):.4f} "
+        for rec in train_coded(cfg, opt, params, opt_state,
+                               steps=args.steps, batch=args.batch,
+                               seq=args.seq, workers=args.workers,
+                               straggler_prob=args.straggler_prob,
+                               n_slots=args.slots, start_step=start_step,
+                               ckpt=ck, ckpt_every=args.ckpt_every):
+            if rec.step % args.log_every == 0:
+                res = rec.epoch
+                print(f"step {rec.step:4d} loss={rec.loss:.4f} "
+                      f"step_s={rec.seconds:.3f} "
                       f"sim_epoch_time={res.time:.3f} "
                       f"util={res.utilization:.2f} "
                       f"stragglers={res.n_stragglers}")
-            if ck and step and step % args.ckpt_every == 0:
-                ck.async_save(step, {"params": params, "opt": opt_state})
-        if ck:
-            ck.wait()
         print(f"done in {time.time()-t0:.1f}s")
         return
 
@@ -164,12 +217,6 @@ def main(argv=None):
         return tfm.loss_fn(params, batch, cfg)
 
     step_fn = jax.jit(make_train_step(loss_fn, opt, clip_norm=1.0))
-    opt_state = opt.init(params)
-    if ck and ck.latest_step() is not None:
-        start_step, t = ck.restore({"params": params, "opt": opt_state})
-        params, opt_state = t["params"], t["opt"]
-        print(f"resumed from step {start_step}")
-    t0 = time.time()
     for step in range(start_step, args.steps):
         part = ds.partition(step, 0)
         batch = {"tokens": part["tokens"], "labels": part["labels"],

@@ -446,7 +446,9 @@ def _apply_unit(x, unit_params, cfg: ModelConfig, kinds, dp_shards, positions,
 # ===================================================================== #
 def _embed_inputs(params, batch, cfg: ModelConfig):
     dt = jnp.dtype(cfg.compute_dtype)
-    emb = params["embed"].astype(dt)
+    # gather from the param-dtype table, then cast: the backward then
+    # scatter-adds a repeated token's gradients in that dtype, not in bf16
+    emb = params["embed"]
     if cfg.frontend == "audio":
         x = batch["frames"].astype(dt) @ params["adapter"].astype(dt)
         S = x.shape[1]
@@ -457,10 +459,10 @@ def _embed_inputs(params, batch, cfg: ModelConfig):
                               jnp.cos(pos[:, None] * freq)], axis=-1)
         return x + pe[None].astype(dt)
     if cfg.frontend == "vision":
-        tok = jnp.take(emb, batch["tokens"], axis=0)
+        tok = jnp.take(emb, batch["tokens"], axis=0).astype(dt)
         patches = batch["patches"].astype(dt) @ params["adapter"].astype(dt)
         return jnp.concatenate([patches, tok], axis=1)
-    return jnp.take(emb, batch["tokens"], axis=0)
+    return jnp.take(emb, batch["tokens"], axis=0).astype(dt)
 
 
 def _lm_head(params, cfg: ModelConfig):

@@ -14,7 +14,7 @@ Bit-identity contract (``tests/test_device_epoch.py``): the carry update
 mirrors ``_StopTracker.consume`` operation for operation —
 
   * byte ledgers and energy extrema accumulate in float64 in the same
-    per-slot order, under ``jax.experimental.enable_x64`` (the f32 slot
+    per-slot order, under a scoped ``jax.enable_x64(True)`` (the f32 slot
     physics is untouched: its inputs stay f32 and every scalar literal is
     weakly typed);
   * the axis sums feeding the idle/stuck predicates replicate numpy's
@@ -48,7 +48,6 @@ from typing import List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 from jax.sharding import PartitionSpec
 
 from repro.core.lyapunov import Observation, QueueState, batched_schedule_slot
@@ -251,14 +250,13 @@ def _tail_runner(channel_step, S: int, M: int, G: int, mesh):
         return jax.jit(run)
     # seed-axis shard_map: per-lane data shards, the shared slot index
     # stays replicated; no in-scan op crosses lanes, so no collectives
-    from jax.experimental.shard_map import shard_map
     lanes = PartitionSpec(SEED_AXIS)
     xs_spec = {"k": PartitionSpec(),
                "h": PartitionSpec(None, SEED_AXIS)}
     xs_spec["ch" if stateful else "r"] = PartitionSpec(None, SEED_AXIS)
-    sharded = shard_map(run, mesh=mesh,
-                        in_specs=(lanes, xs_spec, lanes, lanes),
-                        out_specs=lanes, check_rep=False)
+    sharded = jax.shard_map(run, mesh=mesh,
+                            in_specs=(lanes, xs_spec, lanes, lanes),
+                            out_specs=lanes, check_vma=False)
     return jax.jit(sharded)
 
 
@@ -334,7 +332,7 @@ def device_comm(clusters: Sequence[EdgeCluster],
     n_chunks = -(-grid_len // chunk)
     # the f64 carry/constants only exist under x64; the jit cache is keyed
     # on the flag, so the traced program is stable across re-entries
-    with enable_x64():
+    with jax.enable_x64(True):
         gconsts = (jnp.asarray(gb64, jnp.float64),
                    jnp.asarray(last_visible, jnp.int32),
                    jnp.asarray(tiny, jnp.float64),

@@ -35,7 +35,7 @@ Design (mirrors ``repro.sim.batched`` / ``repro.sim.device_epoch``):
       ``Σ t·qtot`` (``t`` counted from the warmup boundary; ``Σt`` and
       ``Σt²`` are closed forms the host adds back).  Memory is O(S·M)
       regardless of horizon — no per-slot series is ever materialized.
-      The f64 half lives under a scoped ``jax.experimental.enable_x64``
+      The f64 half lives under a scoped ``jax.enable_x64(True)``
       while the f32 slot physics is unchanged (inputs keep their dtypes,
       literals stay weak) — the ``device_epoch`` idiom.
 
@@ -60,12 +60,11 @@ from __future__ import annotations
 import dataclasses
 import math
 from functools import lru_cache
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core.lyapunov import (Observation, QueueState,
                                  batched_schedule_slot_theta,
@@ -376,7 +375,7 @@ def run_soak(lanes: Sequence[SoakLane], n_slots: int, *,
         raise ValueError(f"chunk must be >= 1, got {chunk}")
     g = _stack_group(lanes)
     key = jax.random.PRNGKey(seed)
-    with enable_x64():
+    with jax.enable_x64(True):
         carry = _init_carry(g)
         w32, k0 = jnp.int32(warmup), 0
         consts = {k: v for k, v in g.items()

@@ -46,9 +46,10 @@ def jain_index(x) -> float:
     x = np.asarray(x, np.float64).ravel()
     if x.size and (x < 0).any():
         raise ValueError("jain_index wants non-negative shares")
-    total = x.sum()
-    if x.size == 0 or total <= 0.0:
+    if x.size == 0 or x.sum() <= 0.0:
         return 1.0
+    x = x / x.max()       # scale-free; keeps tiny shares from underflowing
+    total = x.sum()
     return float(total * total / (x.size * np.square(x).sum()))
 
 

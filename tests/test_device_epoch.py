@@ -25,8 +25,8 @@ import sys
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.sim import (BatchedFleet, Fleet, available_scenarios,
                        build_cluster, scenario_spec)
@@ -52,7 +52,7 @@ def test_pairwise_last_is_bitwise_numpy_sum(n, dtype):
     rng = np.random.default_rng(n + (0 if dtype is np.float64 else 1))
     x = (rng.uniform(-1.0, 1.0, (3, n))
          * 10.0 ** rng.integers(-6, 6, (3, n))).astype(dtype)
-    with enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(_pairwise_last(jnp.asarray(x)))
     want = x.sum(axis=-1)
     assert got.dtype == want.dtype
@@ -104,7 +104,6 @@ def test_stack_gates_rejects_missing_gates():
 # mesh validation fails loudly
 # --------------------------------------------------------------------- #
 def test_device_comm_rejects_mesh_without_seed_axis():
-    import jax
     spec = scenario_spec("homogeneous")
     clusters = [build_cluster(spec, "two-stage", s) for s in SEEDS]
     jobs = [c.comm_job(0) for c in clusters]
@@ -114,7 +113,6 @@ def test_device_comm_rejects_mesh_without_seed_axis():
 
 
 def test_batched_fleet_rejects_mesh_with_host_tail():
-    import jax
     spec = scenario_spec("homogeneous")
     with pytest.raises(ValueError, match="mesh= requires tail='device'"):
         BatchedFleet(spec, "two-stage", SEEDS,

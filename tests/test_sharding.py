@@ -1,21 +1,19 @@
 """Sharding-rule unit tests on abstract production meshes (no devices)."""
-import math
-
 import jax
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import get_config, list_archs
-from repro.launch.mesh import abstract_mesh
 from repro.launch.sharding import (_fit_spec_to_shape, batch_shardings,
                                    cache_shardings, param_shardings,
                                    rules_for)
 from repro.models import transformer as tfm
 from repro.models.common import Spec
 
-MESH_1POD = abstract_mesh((16, 16), ("data", "model"))
-MESH_2POD = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH_1POD = AbstractMesh((16, 16), ("data", "model"))
+MESH_2POD = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _check_divisible(sharding, shape, mesh):

@@ -253,7 +253,6 @@ def test_run_horizon_f64_reference():
     — individual slots may diverge after a threshold flips on a ~1e-7
     margin, but the time averages re-converge (measured gap < 0.5%;
     bound 5%, throughput 1%)."""
-    from jax.experimental import enable_x64
     lane = SoakLane(scenario=scenario_spec("heterogeneous-rates")
                     .with_overrides(V=8.0))
     n = 10_000
@@ -277,7 +276,7 @@ def test_run_horizon_f64_reference():
             return jax.lax.scan(body, cast(initial_state(lane)), cast(obs))
 
         if x64:
-            with enable_x64():
+            with jax.enable_x64(True):
                 _, out = go()
                 return [np.asarray(a, np.float64) for a in out]
         _, out = go()
