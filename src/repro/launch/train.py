@@ -3,15 +3,13 @@
 Runs the full stack: synthetic partitioned data pipeline → (optionally)
 two-stage coded gradient runtime → train step → checkpointing/resume.
 ``--reduced`` (the default) picks an architecture's small config,
-``--no-reduced`` its published one, and ``--layers`` cuts depth.  The coded
-path donates params and optimizer state to its step, so a model whose
-state alone takes half the device's memory still fits.
+``--no-reduced`` its published one.  The coded path donates params and
+optimizer state to its step, so a model whose state alone takes half the
+device's memory still fits.
 
 Examples:
   python -m repro.launch.train --arch tiny --steps 50
   python -m repro.launch.train --arch qwen3-14b --reduced --steps 20 --coded
-  python -m repro.launch.train --arch stablelm-1.6b --no-reduced --layers 4 \
-      --coded --batch 1 --seq 256 --slots 5 --steps 5
   python -m repro.launch.train --preset 100m --steps 300 --ckpt-dir ck
 """
 from __future__ import annotations
@@ -49,8 +47,6 @@ def _config(args) -> ModelConfig:
         cfg = TINY
     else:
         cfg = get_config(args.arch, reduced=args.reduced)
-    if args.layers:
-        cfg = dataclasses.replace(cfg, n_layers=args.layers)
     return cfg
 
 
@@ -104,6 +100,14 @@ def slot_batch(ds, plan, step: int) -> dict:
         for key in sample}
 
 
+def coded_step_fn(cfg: ModelConfig, opt):
+    """The jitted coded train step ``(params, opt_state, slot_batch,
+    weights)``.  Params and optimizer state are donated: the update writes
+    over them."""
+    return jax.jit(make_coded_train_step(per_slot_lm_loss(cfg), opt),
+                   donate_argnums=(0, 1))
+
+
 @dataclasses.dataclass
 class CodedStep:
     """One coded training step: its loss, its device time (dispatch to
@@ -127,9 +131,7 @@ def train_coded(cfg: ModelConfig, opt, params, opt_state, *, steps: int,
                             n_slots=n_slots)
     ds = SyntheticLMDataset(runtime.K, examples_per_partition=batch,
                             seq_len=seq, vocab=cfg.vocab)
-    # params and optimizer state are donated: the update writes over them
-    step_fn = jax.jit(make_coded_train_step(per_slot_lm_loss(cfg), opt),
-                      donate_argnums=(0, 1))
+    step_fn = coded_step_fn(cfg, opt)
     for step in range(start_step, steps):
         res = runtime.run_epoch(step)
         sb = slot_batch(ds, res.plan, step)
@@ -154,8 +156,6 @@ def main(argv=None):
                     default=True,
                     help="the architecture's small config (--no-reduced: "
                          "its published widths)")
-    ap.add_argument("--layers", type=int, default=0,
-                    help="cut the config to this many layers (0 = keep)")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -164,8 +164,6 @@ def main(argv=None):
                     help="two-stage coded gradient runtime (simulated "
                          "heterogeneous workers)")
     ap.add_argument("--workers", type=int, default=6)
-    ap.add_argument("--slots", type=int, default=0,
-                    help="coded slot width per worker (0 = per epoch)")
     ap.add_argument("--straggler-prob", type=float, default=0.2)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=25)
@@ -197,7 +195,7 @@ def main(argv=None):
                                steps=args.steps, batch=args.batch,
                                seq=args.seq, workers=args.workers,
                                straggler_prob=args.straggler_prob,
-                               n_slots=args.slots, start_step=start_step,
+                               start_step=start_step,
                                ckpt=ck, ckpt_every=args.ckpt_every):
             if rec.step % args.log_every == 0:
                 res = rec.epoch
