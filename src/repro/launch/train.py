@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import math
 import time
 from typing import Iterator
 
@@ -31,6 +32,7 @@ from repro.data.pipeline import SyntheticLMDataset
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tfm
 from repro.optim import adamw
+from repro.telemetry.annotation import annotate
 
 TINY = ModelConfig(name="tiny", family="dense", n_layers=2, d_model=128,
                    n_heads=4, n_kv_heads=2, head_dim=32, d_ff=256,
@@ -87,17 +89,42 @@ def coded_runtime(workers: int, *, straggler_prob: float = 0.2,
 
 def slot_batch(ds, plan, step: int) -> dict:
     """Stack the epoch's partitions into the plan's (M, n_slots, b, S)
-    layout; unused slots (partition -1) get zeros, and zero weight."""
-    used = plan.slot_partition[plan.slot_partition >= 0]
-    parts = {int(k): {key: np.asarray(v)
-                      for key, v in ds.partition(step, int(k)).items()}
-             for k in np.unique(used)}
+    layout; unused slots (partition -1) get zeros, and zero weight.
+
+    Profiler spans: ``coded.batch.data`` (the plan's partitions from the
+    dataset, as host arrays), ``coded.batch.layout`` (the stack into the
+    slot layout) and ``coded.batch.h2d`` (the slot batch to the device).
+    """
+    ks = np.unique(plan.slot_partition[plan.slot_partition >= 0])
+    with annotate("coded.batch.data", partitions=len(ks)):
+        parts = {int(k): {key: np.asarray(v)
+                          for key, v in ds.partition(step, int(k)).items()}
+                 for k in ks}
     sample = next(iter(parts.values()))
     empty = {key: np.zeros_like(v) for key, v in sample.items()}
-    return {key: jnp.asarray(np.stack([
-        np.stack([parts[int(k)][key] if k >= 0 else empty[key]
-                  for k in row]) for row in plan.slot_partition]))
-        for key in sample}
+    with annotate("coded.batch.layout"):
+        host = {key: np.stack([
+            np.stack([parts[int(k)][key] if k >= 0 else empty[key]
+                      for k in row]) for row in plan.slot_partition])
+            for key in sample}
+    with annotate("coded.batch.h2d"):
+        return {key: jnp.asarray(v) for key, v in host.items()}
+
+
+def slot_counts(plan, batch_shape) -> dict:
+    """The ``coded.batch`` span's counters for one step, from what the
+    step is handed: the plan's ``slot_partition`` (M, n_slots) and the
+    slot batch's shape (M, n_slots, b, S).  Padding slots hold partition
+    -1 and zero weight; ``partition_tokens`` are the tokens of the
+    distinct partitions the plan holds."""
+    sp = plan.slot_partition
+    used = sp[sp >= 0]
+    partitions = len(np.unique(used))
+    return {"slots": int(sp.size), "used_slots": int(used.size),
+            "padding_slots": int(sp.size - used.size),
+            "partitions": partitions,
+            "slot_tokens": math.prod(batch_shape),
+            "partition_tokens": partitions * math.prod(batch_shape[2:])}
 
 
 def coded_step_fn(cfg: ModelConfig, opt):
@@ -111,8 +138,9 @@ def coded_step_fn(cfg: ModelConfig, opt):
 @dataclasses.dataclass
 class CodedStep:
     """One coded training step: its loss, its device time (dispatch to
-    ``block_until_ready``; the first step's includes compilation) and the
-    runtime's epoch result (plan, weights, simulated epoch time)."""
+    ``block_until_ready``, the ``coded.device_step`` span; the first
+    step's includes compilation) and the runtime's epoch result (plan,
+    weights, simulated epoch time)."""
     step: int
     loss: float
     seconds: float
@@ -126,24 +154,43 @@ def train_coded(cfg: ModelConfig, opt, params, opt_state, *, steps: int,
                 ) -> Iterator[CodedStep]:
     """Run the two-stage coded loop, yielding one :class:`CodedStep` per
     step.  ``params``/``opt_state`` are donated to the first step: the
-    caller's arrays are consumed."""
+    caller's arrays are consumed.
+
+    Each step is a ``coded.step`` profiler span (a step marker, closed
+    before the yield, so the consumer's time lies outside it) holding
+    ``coded.plan`` (the runtime's epoch plan), ``coded.batch`` (the slot
+    batch, see :func:`slot_batch`, and the decode weights' transfer; its
+    args are :func:`slot_counts` with ``step``, ``stage2`` and
+    ``decode_ok``), ``coded.device_step`` (dispatch to
+    ``block_until_ready``, the interval ``CodedStep.seconds`` times) and
+    ``coded.loss_fetch``."""
     runtime = coded_runtime(workers, straggler_prob=straggler_prob,
                             n_slots=n_slots)
     ds = SyntheticLMDataset(runtime.K, examples_per_partition=batch,
                             seq_len=seq, vocab=cfg.vocab)
     step_fn = coded_step_fn(cfg, opt)
     for step in range(start_step, steps):
-        res = runtime.run_epoch(step)
-        sb = slot_batch(ds, res.plan, step)
-        w = jnp.asarray(res.weights, jnp.float32)
-        t0 = time.perf_counter()
-        params, opt_state, aux = jax.block_until_ready(
-            step_fn(params, opt_state, sb, w))
-        dt = time.perf_counter() - t0
-        if ckpt and step and step % ckpt_every == 0:
-            ckpt.async_save(step, {"params": params, "opt": opt_state})
-        yield CodedStep(step=step, loss=float(aux["loss"]), seconds=dt,
-                        epoch=res)
+        with annotate("coded.step", step_num=step):
+            with annotate("coded.plan"):
+                res = runtime.run_epoch(step)
+            with annotate("coded.batch", step=step) as span:
+                sb = slot_batch(ds, res.plan, step)
+                w = jnp.asarray(res.weights, jnp.float32)
+                if span.is_enabled():       # a profiler trace is running
+                    span.set_metadata(
+                        **slot_counts(res.plan, sb["tokens"].shape),
+                        stage2=bool(res.stage2_triggered),
+                        decode_ok=bool(res.decode_ok))
+            with annotate("coded.device_step"):
+                t0 = time.perf_counter()
+                params, opt_state, aux = jax.block_until_ready(
+                    step_fn(params, opt_state, sb, w))
+                dt = time.perf_counter() - t0
+            if ckpt and step and step % ckpt_every == 0:
+                ckpt.async_save(step, {"params": params, "opt": opt_state})
+            with annotate("coded.loss_fetch"):
+                loss = float(aux["loss"])
+        yield CodedStep(step=step, loss=loss, seconds=dt, epoch=res)
     if ckpt:
         ckpt.wait()
 

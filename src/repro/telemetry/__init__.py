@@ -3,6 +3,9 @@
 Per-slot scheduler series, phase timing and compile accounting for the
 co-simulated fleets, with a zero-cost off switch:
 
+  * :func:`annotate` — the one span helper: a ``jax.profiler``
+    annotation, on the device trace's clock (the coded training loop's
+    spans and the recorder's phase spans);
   * :class:`TelemetryConfig` / :class:`FleetRecorder` — the recorder both
     engines thread through their epoch loops (``telemetry=`` on
     ``BatchedFleet`` / ``run_fleet``; attribute on ``EdgeCluster``);
@@ -17,8 +20,8 @@ co-simulated fleets, with a zero-cost off switch:
     entry point (lazily imported: it pulls in the simulator, which in
     turn imports this package).
 """
-from repro.telemetry.compilation import (compile_counts, note_compile,
-                                         reset_compile_counts)
+from repro.telemetry.annotation import annotate
+from repro.telemetry.compilation import compile_counts, note_compile
 from repro.telemetry.metrics import (fleet_fairness, jain_index,
                                      mean_queue_residual,
                                      queue_stability_drift,
@@ -30,10 +33,10 @@ from repro.telemetry.trace import chrome_trace_events, write_chrome_trace
 
 __all__ = [
     "TelemetryConfig", "FleetRecorder", "Span", "SERIES_FIELDS",
-    "phase_span",
+    "phase_span", "annotate",
     "jain_index", "fleet_fairness", "mean_queue_residual",
     "queue_stability_drift", "straggler_rate_ewma",
-    "note_compile", "compile_counts", "reset_compile_counts",
+    "note_compile", "compile_counts",
     "JsonlSink", "MemorySink",
     "chrome_trace_events", "write_chrome_trace",
     "record_fleet",

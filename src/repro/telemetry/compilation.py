@@ -24,7 +24,7 @@ from __future__ import annotations
 from collections import Counter
 from typing import Dict
 
-__all__ = ["note_compile", "compile_counts", "reset_compile_counts"]
+__all__ = ["note_compile", "compile_counts"]
 
 _counts: Counter = Counter()
 
@@ -37,16 +37,8 @@ def note_compile(name: str) -> None:
 
 
 def compile_counts() -> Dict[str, int]:
-    """Snapshot of all compile counters since process start (or the last
-    :func:`reset_compile_counts`)."""
+    """Snapshot of all compile counters since process start."""
     return dict(_counts)
-
-
-def reset_compile_counts() -> None:
-    """Zero every counter.  Note this does *not* drop any jit cache —
-    pair it with ``repro.sim.batched.reset_scan_compile_cache`` when a
-    test needs compilations to actually re-happen."""
-    _counts.clear()
 
 
 # Subscribe to the scheduler's trace hook so every schedule_slot retrace

@@ -14,7 +14,9 @@ of record in memory:
     equal on every registry scenario × scheme;
   * **phase spans** — wall-clock ``(t0, t1)`` intervals around the
     stage-1 / stage-2 / comm / decode phases of every epoch, exportable
-    as a Chrome/Perfetto trace (:mod:`repro.telemetry.trace`);
+    as a Chrome/Perfetto trace (:mod:`repro.telemetry.trace`); each is
+    also a profiler annotation (:func:`~repro.telemetry.annotate`), so a
+    running ``jax.profiler`` trace holds it too;
   * **epoch events** — the scalar per-(lane, epoch) outcome summary
     (decode, slots, times, byte totals) the report CLI tabulates;
   * **compile accounting** — the delta of the named compile counters
@@ -40,6 +42,7 @@ from typing import Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
+from repro.telemetry.annotation import annotate
 from repro.telemetry.compilation import compile_counts
 
 __all__ = ["TelemetryConfig", "FleetRecorder", "Span", "SERIES_FIELDS",
@@ -48,8 +51,7 @@ __all__ = ["TelemetryConfig", "FleetRecorder", "Span", "SERIES_FIELDS",
 #: Per-slot series recorded for every (lane, epoch) comm phase, all
 #: ``(n_slots, M)``: post-slot queue backlog / virtual queue / battery,
 #: plus the slot's admissions, transmissions and post-slot worker-pending
-#: bytes.  Field names are shared verbatim by both engines and the JSONL
-#: schema.
+#: bytes.  Field names are shared verbatim by both engines.
 SERIES_FIELDS = ("Q", "H", "E", "admitted", "transmitted", "pending")
 
 
@@ -57,15 +59,12 @@ SERIES_FIELDS = ("Q", "H", "E", "admitted", "transmitted", "pending")
 class TelemetryConfig:
     """What a recorder collects.  ``enabled=False`` makes the recorder
     falsy — engines then skip every telemetry branch (the off switch).
-
-    ``sink_slots`` controls whether :meth:`FleetRecorder.flush` emits the
-    (potentially large) per-slot series as JSONL events in addition to
-    keeping them in memory; spans/epochs/compile counters always flush.
+    The per-slot series stay in memory; :meth:`FleetRecorder.flush`
+    emits spans, epochs and compile counters.
     """
     enabled: bool = True
     series: bool = True         # collect per-slot comm series
     spans: bool = True          # collect wall-clock phase spans
-    sink_slots: bool = False    # emit slot events on flush (verbose)
 
 
 @dataclasses.dataclass
@@ -151,13 +150,15 @@ class FleetRecorder:
     # -- phase spans ---------------------------------------------------- #
     @contextlib.contextmanager
     def span(self, name: str, **meta) -> Iterator[None]:
-        """Record the wall-clock of the enclosed block as a named span."""
+        """Record the wall-clock of the enclosed block as a named span,
+        and annotate it for the profiler with ``meta`` as its args."""
         if not self.wants_spans:
             yield
             return
         t0 = time.perf_counter()
         try:
-            yield
+            with annotate(name, **meta):
+                yield
         finally:
             self.spans.append(Span(name, t0, time.perf_counter(),
                                    dict(meta)))
@@ -205,8 +206,8 @@ class FleetRecorder:
     # -- sink flush ----------------------------------------------------- #
     def events(self) -> Iterator[dict]:
         """The run as a flat, JSON-serializable event stream: one ``run``
-        header, then ``epoch`` / ``span`` / optional ``slot`` events and
-        a final ``compiles`` record (the JSONL schema of
+        header, then ``epoch`` and ``span`` events and a final
+        ``compiles`` record (the JSONL schema of
         :mod:`repro.telemetry.sinks` / ``repro.telemetry.report``)."""
         yield {"type": "run", **self.meta}
         for ev in self.epoch_events():
@@ -214,14 +215,6 @@ class FleetRecorder:
         for sp in self.spans:
             yield {"type": "span", "name": sp.name, "t0": sp.t0,
                    "t1": sp.t1, **sp.meta}
-        if self.config.sink_slots:
-            for (lane, epoch), series in sorted(self._series.items()):
-                n = series[SERIES_FIELDS[0]].shape[0]
-                for k in range(n):
-                    yield {"type": "slot", "lane": lane, "epoch": epoch,
-                           "slot": k,
-                           **{f: series[f][k].tolist()
-                              for f in SERIES_FIELDS}}
         yield {"type": "compiles", "counts": self.compile_delta()}
 
     def flush(self, *sinks) -> None:

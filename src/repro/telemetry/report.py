@@ -51,7 +51,7 @@ def load_runs(paths: Iterable[str]) -> List[dict]:
                 kind = ev.pop("type", None)
                 if kind == "run":
                     run = {"meta": ev, "epochs": [], "spans": [],
-                           "slots": [], "compiles": {}}
+                           "compiles": {}}
                     runs.append(run)
                 elif run is None:
                     raise ValueError(f"{path}:{i + 1}: {kind!r} event "
@@ -60,8 +60,6 @@ def load_runs(paths: Iterable[str]) -> List[dict]:
                     run["epochs"].append(ev)
                 elif kind == "span":
                     run["spans"].append(ev)
-                elif kind == "slot":
-                    run["slots"].append(ev)
                 elif kind == "compiles":
                     for k, v in ev.get("counts", {}).items():
                         run["compiles"][k] = run["compiles"].get(k, 0) + v

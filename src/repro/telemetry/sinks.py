@@ -2,7 +2,7 @@
 
 A sink is anything with ``write(event: dict)`` — the recorder's
 :meth:`~repro.telemetry.recorder.FleetRecorder.flush` pushes its event
-stream (``run`` / ``epoch`` / ``span`` / ``slot`` / ``compiles`` records,
+stream (``run`` / ``epoch`` / ``span`` / ``compiles`` records,
 see ``FleetRecorder.events``) through every sink it is given.  Multiple
 runs may be flushed into one JSONL file; each run's ``run`` header resets
 the reader's context (``repro.telemetry.report`` relies on this).
