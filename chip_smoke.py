@@ -73,7 +73,7 @@ SEED = 0
 #: Trainer cell: stablelm-1.6b at published widths, 4 layers; 6 workers
 #: over K=12 partitions, 5 slots each, one 384-token sequence per slot —
 #: 11,520 tokens a step.  A v5e compile of this step (params and Adam
-#: state donated) needs 14.14 GiB of the chip's 15.75 GiB.
+#: state donated) needs 13.41 GiB of the chip's 15.75 GiB.
 TRAIN = dict(arch="stablelm-1.6b", layers=4, workers=6, slots=5, batch=1,
              seq=384, steps=5)
 #: Gradient checks, relative L2 on epoch CHECK_EPOCH's plan.  Three

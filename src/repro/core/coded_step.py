@@ -13,17 +13,34 @@ So the coded step costs ZERO extra collectives versus plain data-parallel
 SGD, and the straggler pattern enters as runtime data (weights), never as a
 recompile.  The host-side TwoStageRuntime (core/runtime.py) builds the slot
 assignment + weights each epoch.
+
+Zero-weight slots are skipped when they can be.  Padding slots and every
+slot of a worker the decode discards carry weight exactly 0, so they add
+exactly 0 to the loss and the gradient.  When the nonzero weights fit in
+one row of ``n_slots`` slots (``nnz <= n_slots``), the step gathers those
+slots, nonzero first in a stable order over the flattened (M, n_slots)
+layout, and runs forward and backward over that one row only.  Otherwise
+it runs the whole layout as before: a plan that needs more than one row
+costs what it did without the row, not more (no per-row accumulations,
+and in bf16 the same whole-batch rounding of the weight gradients).  A
+``lax.cond`` on the weights picks the branch: one executable for every
+plan.  The gather mixes workers' slots in one row, so it holds only while
+all M workers' slots live on one device; a worker axis sharded over a
+mesh would turn it into a cross-chip shuffle (ROADMAP R3).
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
-__all__ = ["SlotPlan", "build_slot_plan", "slot_weights",
+__all__ = ["SlotPlan", "build_slot_plan", "slot_weights", "computed_rows",
            "make_train_step", "coded_value_and_grad", "make_coded_train_step"]
 
 
@@ -99,31 +116,79 @@ def make_train_step(loss_fn: Callable, optimizer, *,
     return step
 
 
+def computed_rows(weights, xp=jnp):
+    """Rows of ``n_slots`` slots that the coded step computes for the
+    (M, n_slots) ``weights``: 1 when the nonzero weights fit in one row
+    (``nnz <= n_slots``), else all M.  ``xp=np`` counts on the host."""
+    M, n_slots = weights.shape
+    return xp.where(xp.count_nonzero(weights) <= n_slots, 1, M)
+
+
+def _one_row(slot_batch, weights):
+    """The nonzero-weight slots first, in a stable order over the
+    flattened layout, cut to one row: a ``(1, n_slots, ...)`` batch and
+    its ``(1, n_slots)`` weights."""
+    M, n_slots = weights.shape
+    flat = weights.reshape(M * n_slots)
+    idx = jnp.argsort(flat == 0, stable=True)[:n_slots]   # nonzero first
+    batch = jax.tree.map(
+        lambda a: a.reshape(M * n_slots, *a.shape[2:])[idx][None],
+        slot_batch)
+    return batch, flat[idx][None]
+
+
+def _on_computed_rows(fn, state, slot_batch, weights):
+    """``fn(state, batch, w)`` on the :func:`computed_rows` rows: one
+    packed row, or the whole layout.  One ``lax.cond``, so every plan
+    shares one executable."""
+    return lax.cond(computed_rows(weights) == 1,
+                    lambda s, b, w: fn(s, *_one_row(b, w)), fn,
+                    state, slot_batch, weights)
+
+
+def _weighted_value_and_grad(per_slot_loss_fn: Callable) -> Callable:
+    def fn(params, batch, w):
+        return jax.value_and_grad(
+            lambda p: jnp.sum(per_slot_loss_fn(p, batch) * w))(params)
+    return fn
+
+
 def coded_value_and_grad(per_slot_loss_fn: Callable) -> Callable:
     """(params, slot_batch, weights) -> (weighted loss, decoded gradient).
 
     ``per_slot_loss_fn(params, slot_batch) -> (M, n_slots)`` per-slot mean
-    losses.  Contracting them with the runtime-supplied weight matrix
-    (a_m·B[m,k]) makes the gradient, by linearity, the exact decoded full
-    gradient Σ_k g_k.
+    losses; it is also called on one row, a ``(1, n_slots, ...)`` batch.
+    Contracting them with the runtime-supplied weight matrix (a_m·B[m,k])
+    makes the gradient, by linearity, the exact decoded full gradient
+    Σ_k g_k.  Only the :func:`computed_rows` rows are computed: the one
+    row of nonzero-weight slots, or the whole layout (module docstring).
     """
-    def fn(params, slot_batch, weights):
-        def total_loss(p):
-            per_slot = per_slot_loss_fn(p, slot_batch)       # (M, n_slots)
-            return jnp.sum(per_slot * weights)
-        return jax.value_and_grad(total_loss)(params)
-
-    return fn
+    weighted = _weighted_value_and_grad(per_slot_loss_fn)
+    return functools.partial(_on_computed_rows, weighted)
 
 
 def make_coded_train_step(per_slot_loss_fn: Callable, optimizer) -> Callable:
     """Coded step over slotted batches: the :func:`coded_value_and_grad`
-    gradient, then one optimizer update."""
-    value_and_grad = coded_value_and_grad(per_slot_loss_fn)
+    gradient, then one optimizer update.  ``aux`` holds the weighted loss
+    and the rows computed (:func:`computed_rows`).  The update runs inside
+    the same branch as the gradient, so that the compiler can fuse the
+    weight gradients into it as it does without the branch."""
+    weighted = _weighted_value_and_grad(per_slot_loss_fn)
+
+    def update(state, batch, w):
+        params, opt_state = state
+        loss, grads = weighted(params, batch, w)
+        # row-major gradients: inside a branch the compiler otherwise runs
+        # the attention weights' update in a transposed layout, copying
+        # params and moments in and out (7.5 ms a step on a v5e)
+        grads = jax.tree.map(lambda g: with_layout_constraint(
+            g, Layout(major_to_minor=tuple(range(g.ndim)))), grads)
+        return optimizer.update(grads, opt_state, params), loss
 
     def step(params, opt_state, slot_batch, weights):
-        loss, grads = value_and_grad(params, slot_batch, weights)
-        params, opt_state = optimizer.update(grads, opt_state, params)
-        return params, opt_state, {"loss": loss}
+        (params, opt_state), loss = _on_computed_rows(
+            update, (params, opt_state), slot_batch, weights)
+        return params, opt_state, {"loss": loss,
+                                   "rows": computed_rows(weights)}
 
     return step

@@ -26,7 +26,8 @@ import numpy as np
 
 from repro.checkpoint import Checkpointer
 from repro.configs.base import ModelConfig, get_config
-from repro.core.coded_step import make_coded_train_step, make_train_step
+from repro.core.coded_step import (computed_rows, make_coded_train_step,
+                                   make_train_step)
 from repro.core.runtime import EpochResult, TwoStageRuntime
 from repro.data.pipeline import SyntheticLMDataset
 from repro.launch.compile_cache import enable_compile_cache
@@ -111,17 +112,21 @@ def slot_batch(ds, plan, step: int) -> dict:
         return {key: jnp.asarray(v) for key, v in host.items()}
 
 
-def slot_counts(plan, batch_shape) -> dict:
+def slot_counts(plan, weights, batch_shape) -> dict:
     """The ``coded.batch`` span's counters for one step, from what the
-    step is handed: the plan's ``slot_partition`` (M, n_slots) and the
-    slot batch's shape (M, n_slots, b, S).  Padding slots hold partition
-    -1 and zero weight; ``partition_tokens`` are the tokens of the
-    distinct partitions the plan holds."""
+    step is handed: the plan's ``slot_partition`` (M, n_slots), the decode
+    weights (M, n_slots) and the slot batch's shape (M, n_slots, b, S).
+    Padding slots hold partition -1 and zero weight; ``partition_tokens``
+    are the tokens of the distinct partitions the plan holds;
+    ``computed_rows`` and ``computed_slots`` are the rows of the layout,
+    and their slots, that the step computes, by the step's own rule."""
     sp = plan.slot_partition
     used = sp[sp >= 0]
     partitions = len(np.unique(used))
+    rows = int(computed_rows(np.asarray(weights, np.float32), np))
     return {"slots": int(sp.size), "used_slots": int(used.size),
             "padding_slots": int(sp.size - used.size),
+            "computed_rows": rows, "computed_slots": rows * sp.shape[1],
             "partitions": partitions,
             "slot_tokens": math.prod(batch_shape),
             "partition_tokens": partitions * math.prod(batch_shape[2:])}
@@ -178,7 +183,8 @@ def train_coded(cfg: ModelConfig, opt, params, opt_state, *, steps: int,
                 w = jnp.asarray(res.weights, jnp.float32)
                 if span.is_enabled():       # a profiler trace is running
                     span.set_metadata(
-                        **slot_counts(res.plan, sb["tokens"].shape),
+                        **slot_counts(res.plan, res.weights,
+                                      sb["tokens"].shape),
                         stage2=bool(res.stage2_triggered),
                         decode_ok=bool(res.decode_ok))
             with annotate("coded.device_step"):

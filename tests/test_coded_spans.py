@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
+from repro.launch import train as T
 from repro.launch.train import TINY, train_coded
 from repro.models import transformer as tfm
 from repro.optim import adamw
@@ -55,14 +56,34 @@ def run_steps():
                             workers=WORKERS, n_slots=SLOTS))
 
 
+def rows_recorded(mp, rows: list):
+    """Wrap the trainer's step so that each call's ``aux["rows"]`` lands
+    in ``rows``; what the step computes and returns is unchanged."""
+    make = T.coded_step_fn
+
+    def coded_step_fn(*a, **k):
+        step = make(*a, **k)
+
+        def recorded(*args):
+            out = step(*args)
+            rows.append(int(out[2]["rows"]))
+            return out
+        return recorded
+    mp.setattr(T, "coded_step_fn", coded_step_fn)
+
+
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
-    """The same steps untraced, then under the profiler."""
+    """The same steps untraced, then under the profiler, with the rows
+    each traced step computed."""
     plain = run_steps()
     trace_dir = tmp_path_factory.mktemp("coded-trace")
-    with jax.profiler.trace(str(trace_dir)):
-        recs = run_steps()
-    return plain, recs, host_spans(trace_dir, "coded.")
+    rows = []
+    with pytest.MonkeyPatch.context() as mp:
+        rows_recorded(mp, rows)
+        with jax.profiler.trace(str(trace_dir)):
+            recs = run_steps()
+    return plain, recs, host_spans(trace_dir, "coded."), rows
 
 
 def by_name(spans, name):
@@ -74,7 +95,7 @@ def inside(inner, outer) -> bool:
 
 
 def test_each_span_once_a_step_and_nested(traced):
-    _, recs, spans = traced
+    _, recs, spans, _ = traced
     steps = by_name(spans, "coded.step")
     assert [ev[3]["step_num"] for ev in steps] == [r.step for r in recs]
     for name in INNER + BATCH_PARTS:
@@ -91,7 +112,7 @@ def test_each_span_once_a_step_and_nested(traced):
 
 
 def test_batch_counters_follow_the_plan(traced):
-    _, recs, spans = traced
+    _, recs, spans, _ = traced
     batches = by_name(spans, "coded.batch")
     data = by_name(spans, "coded.batch.data")
     for rec, ev, data_ev in zip(recs, batches, data):
@@ -110,14 +131,27 @@ def test_batch_counters_follow_the_plan(traced):
         assert args["decode_ok"] == int(rec.epoch.decode_ok)
 
 
+def test_batch_counts_the_rows_the_step_computed(traced):
+    _, recs, spans, rows = traced
+    batches = by_name(spans, "coded.batch")
+    assert len(rows) == len(batches) == STEPS
+    for rec, ev, step_rows in zip(recs, batches, rows):
+        args = ev[3]
+        nnz = int(np.count_nonzero(rec.epoch.weights))
+        assert args["computed_rows"] == step_rows \
+            == (1 if nnz <= SLOTS else WORKERS)
+        assert args["computed_slots"] == step_rows * SLOTS
+        assert args["used_slots"] >= nnz
+
+
 def test_device_step_span_is_the_step_seconds(traced):
-    _, recs, spans = traced
+    _, recs, spans, _ = traced
     for rec, ev in zip(recs, by_name(spans, "coded.device_step")):
         assert abs((ev[1] - ev[0]) * 1e-9 - rec.seconds) < 1e-3
 
 
 def test_losses_bitwise_equal_with_profiler_on_and_off(traced):
-    plain, recs, _ = traced
+    plain, recs, _, _ = traced
     assert [r.loss for r in recs] == [r.loss for r in plain]
     assert all(math.isfinite(r.loss) for r in recs)
 
