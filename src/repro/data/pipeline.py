@@ -4,10 +4,11 @@ The dataset D is split into K non-overlapping equal-size partitions
 D = {D_1..D_K}; the coded step assigns each worker a set of partition
 *slots* with coefficients.  The pipeline is:
 
-  * deterministic: (epoch, partition, index) -> example, via counter-based
-    hashing (philox through jax.random), so every worker can materialize any
-    partition without coordination — exactly what coded redundancy needs
-    (two workers computing the same partition MUST see identical bytes);
+  * deterministic: (epoch, partition) -> examples, each partition drawn
+    from its own numpy ``default_rng`` seeded by (seed, epoch, partition),
+    so every worker can materialize any partition without coordination —
+    exactly what coded redundancy needs (two workers computing the same
+    partition MUST see identical bytes);
   * offline: synthetic token streams (language-model cells) or labeled
     feature vectors (the paper's MNIST/CIFAR-like FEL experiments) — no
     downloads in this container;
@@ -60,24 +61,42 @@ class SyntheticLMDataset(PartitionedDataset):
         # sparse-ish transition table for structure
         self._trans = rng.integers(0, vocab, size=(vocab,)).astype(np.int64)
 
-    def partition(self, epoch: int, k: int) -> dict:
-        """Returns {'tokens','labels','weights'} for partition k."""
-        rng = np.random.default_rng(
-            (self.seed * 1_000_003 + epoch) * 131_071 + k)
-        B, S, V = self.n, self.seq_len, self.vocab
-        toks = np.empty((B, S), np.int64)
-        toks[:, 0] = rng.integers(0, V, size=B)
-        noise = rng.random((B, S)) < 0.15
-        rand_tok = rng.integers(0, V, size=(B, S))
+    def host_partitions(self, epoch: int, ks) -> dict:
+        """Partitions ``ks`` of ``epoch`` as host arrays, stacked in the
+        order given: {'tokens', 'labels'} int32 and {'weights'} f32, each
+        (len(ks), b, S).
+
+        Each partition draws from its own generator, in the same order
+        (first tokens, noise mask, random tokens), so a partition's bytes
+        do not depend on which others are drawn with it; only the Markov
+        recurrence over positions runs over all of them at once.
+        """
+        ks = [int(k) for k in ks]
+        P, B, S, V = len(ks), self.n, self.seq_len, self.vocab
+        toks = np.empty((P, B, S), np.int64)
+        noise = np.empty((P, B, S), bool)
+        rand_tok = np.empty((P, B, S), np.int64)
+        for i, k in enumerate(ks):
+            rng = np.random.default_rng(
+                (self.seed * 1_000_003 + epoch) * 131_071 + k)
+            toks[i, :, 0] = rng.integers(0, V, size=B)
+            noise[i] = rng.random((B, S)) < 0.15
+            rand_tok[i] = rng.integers(0, V, size=(B, S))
         for t in range(1, S):
-            nxt = self._trans[toks[:, t - 1]]
-            toks[:, t] = np.where(noise[:, t], rand_tok[:, t], nxt)
-        labels = np.concatenate([toks[:, 1:], toks[:, :1]], axis=1)
+            toks[:, :, t] = np.where(noise[:, :, t], rand_tok[:, :, t],
+                                     self._trans[toks[:, :, t - 1]])
+        labels = np.concatenate([toks[:, :, 1:], toks[:, :, :1]], axis=2)
         w = np.ones((B, S), np.float32)
         w[:, -1] = 0.0                      # no target for last position
-        return {"tokens": jnp.asarray(toks, jnp.int32),
-                "labels": jnp.asarray(labels, jnp.int32),
-                "weights": jnp.asarray(w / w.sum())}
+        return {"tokens": toks.astype(np.int32),
+                "labels": labels.astype(np.int32),
+                "weights": np.broadcast_to(w / w.sum(), (P, B, S)).copy()}
+
+    def partition(self, epoch: int, k: int) -> dict:
+        """Returns {'tokens','labels','weights'} for partition k, as device
+        arrays (:meth:`host_partitions` of the one partition)."""
+        return {key: jnp.asarray(v[0])
+                for key, v in self.host_partitions(epoch, [k]).items()}
 
 
 class SyntheticClassificationDataset(PartitionedDataset):

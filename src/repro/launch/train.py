@@ -89,27 +89,26 @@ def coded_runtime(workers: int, *, straggler_prob: float = 0.2,
 
 
 def slot_batch(ds, plan, step: int) -> dict:
-    """Stack the epoch's partitions into the plan's (M, n_slots, b, S)
+    """Lay the epoch's partitions out in the plan's (M, n_slots, b, S)
     layout; unused slots (partition -1) get zeros, and zero weight.
 
-    Profiler spans: ``coded.batch.data`` (the plan's partitions from the
-    dataset, as host arrays), ``coded.batch.layout`` (the stack into the
-    slot layout) and ``coded.batch.h2d`` (the slot batch to the device).
+    Profiler spans: ``coded.batch.data`` (the plan's distinct partitions,
+    drawn on the host in one :meth:`~SyntheticLMDataset.host_partitions`
+    call), ``coded.batch.layout`` (one gather per key into the slot
+    layout, padding slots reading an appended zero row) and
+    ``coded.batch.h2d`` (the slot batch to the device, one transfer).
     """
-    ks = np.unique(plan.slot_partition[plan.slot_partition >= 0])
+    sp = plan.slot_partition
+    ks = np.unique(sp[sp >= 0])
     with annotate("coded.batch.data", partitions=len(ks)):
-        parts = {int(k): {key: np.asarray(v)
-                          for key, v in ds.partition(step, int(k)).items()}
-                 for k in ks}
-    sample = next(iter(parts.values()))
-    empty = {key: np.zeros_like(v) for key, v in sample.items()}
+        parts = ds.host_partitions(step, ks)
     with annotate("coded.batch.layout"):
-        host = {key: np.stack([
-            np.stack([parts[int(k)][key] if k >= 0 else empty[key]
-                      for k in row]) for row in plan.slot_partition])
-            for key in sample}
+        idx = np.where(sp >= 0, np.searchsorted(ks, sp), len(ks))
+        host = {key: np.concatenate(
+            [v, np.zeros((1,) + v.shape[1:], v.dtype)])[idx]
+            for key, v in parts.items()}
     with annotate("coded.batch.h2d"):
-        return {key: jnp.asarray(v) for key, v in host.items()}
+        return jax.device_put(host)
 
 
 def slot_counts(plan, weights, batch_shape) -> dict:
