@@ -23,7 +23,6 @@ def main(report) -> None:
     from repro.kernels.flash_attention.ref import attention_ref
     from repro.kernels.rglru_scan.ops import rglru_scan_op
     from repro.kernels.rwkv6_wkv.ops import wkv_op
-    from repro.kernels.coded_reduce.ops import coded_reduce_op
 
     rng = np.random.default_rng(0)
     # flash attention (B,S,KV,G,D) = (1,512,2,2,64)
@@ -52,9 +51,3 @@ def main(report) -> None:
     us = _time(lambda: wkv_op(r, r, r, w, u, chunk=32, interpret=True))
     report("kernel_rwkv6_wkv_interpret", us,
            f"state_bytes={4 * 64 * 64 * 4}")
-
-    g = jnp.asarray(rng.standard_normal((8, 4096)), jnp.float32)
-    wts = jnp.asarray(rng.standard_normal((8,)), jnp.float32)
-    us = _time(lambda: coded_reduce_op(g, wts, interpret=True))
-    report("kernel_coded_reduce_interpret", us,
-           f"bytes={g.size * 4:.2e}")

@@ -34,7 +34,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.data.pipeline import SyntheticLMDataset
-from repro.models.transformer import init_params, loss_fn
+from repro.models.transformer import init_params
 from repro.optim.optimizers import adamw
 from repro.sim.cluster import SCHEMES
 from repro.sim.scenarios import scenario_spec
@@ -64,9 +64,7 @@ def run_benchmark(cfg: ModelConfig, *, scenario: str = "bursty-stragglers",
     dataset = SyntheticLMDataset(K=spec.K, examples_per_partition=2,
                                  seq_len=32, vocab=cfg.vocab, seed=0)
     params0 = init_params(cfg, jax.random.PRNGKey(0))
-    # one compiled backward + one optimizer shared by every trainer
-    grad_fn = jax.jit(jax.value_and_grad(
-        lambda p, batch: loss_fn(p, batch, cfg)))
+    # one optimizer object: every trainer shares one compiled coded step
     optimizer = adamw(1e-2)
 
     t_host = time.perf_counter()
@@ -75,7 +73,7 @@ def run_benchmark(cfg: ModelConfig, *, scenario: str = "bursty-stragglers",
     for scheme in schemes:
         for seed in range(n_seeds):
             tr = CodedTrainer(cfg, spec, scheme, dataset, optimizer,
-                              params=params0, seed=seed, grad_fn=grad_fn)
+                              params=params0, seed=seed)
             tr.run(n_epochs)
             runs[scheme].append(tr.logs)
             trainers[scheme] = tr

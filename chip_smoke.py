@@ -49,8 +49,6 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 from repro.configs.base import get_config  # noqa: E402
 from repro.core.coded_step import coded_value_and_grad  # noqa: E402
 from repro.data.pipeline import SyntheticLMDataset  # noqa: E402
-from repro.kernels.coded_reduce.ops import (coded_reduce_op,  # noqa: E402
-                                            coded_reduce_ref)
 from repro.kernels.flash_attention.ops import (attention_ref,  # noqa: E402
                                                flash_attention_op)
 from repro.kernels.rglru_scan.ops import rglru_ref, rglru_scan_op  # noqa: E402
@@ -107,17 +105,16 @@ CHECK_EPOCH = 1
 #: Kernel widths: flash attention at stablelm-1.6b's 32 heads x 64 with
 #: S=4096 (B, S, H, D); the RG-LRU scan at recurrentgemma-2b's d_rnn=2560
 #: (B, S, D); WKV at rwkv6-1.6b's 32 heads x 64 and its chunk of 64
-#: (B, H, S, K); the decode-reduce over 6 slots of one stablelm-1.6b
-#: layer's gradient (its width is read off the config).
+#: (B, H, S, K).
 KERNEL_SHAPES = dict(flash=(1, 4096, 32, 64), rglru=(4, 2048, 2560),
-                     wkv=(1, 32, 2048, 64), wkv_chunk=64, reduce_slots=6)
+                     wkv=(1, 32, 2048, 64), wkv_chunk=64)
 #: The max error allowed per kernel, relative
 #: to max |ref|.  flash: bf16 output rounding; rglru: f32 VPU arithmetic
 #: in a different association order; wkv: f32 chunked form vs sequential
 #: recurrence over 2,048 steps (its inner dots run at Mosaic's default
-#: MXU precision); coded_reduce: an f32 contraction at fp32 precision.
+#: MXU precision).
 KERNEL_TOL = {"flash_attention": 2e-2, "rglru_scan": 1e-4,
-              "rwkv6_wkv": 1e-2, "coded_reduce": 1e-5}
+              "rwkv6_wkv": 1e-2}
 
 #: Co-sim cell and the statistical contract used where lanes differ from
 #: the oracle bitwise: decode-failure rate within 2 lane-epochs of 32,
@@ -193,14 +190,6 @@ def _kernel(name, op, args, kwargs, out, ref):
           f"{KERNEL_TOL[name]}")
 
 
-def layer_payload(cfg) -> int:
-    """Parameters in one layer of ``cfg`` — one decode-reduce payload."""
-    one = dataclasses.replace(cfg, n_layers=1)
-    shapes = jax.eval_shape(lambda: tfm.init_params(one,
-                                                    jax.random.PRNGKey(0)))
-    return sum(a.size for a in jax.tree.leaves(shapes["groups"]))
-
-
 def phase_kernels() -> None:
     ks = iter(jax.random.split(jax.random.PRNGKey(SEED), 16))
     normal = lambda shape, dt=jnp.float32, s=1.0: (  # noqa: E731
@@ -239,15 +228,6 @@ def phase_kernels() -> None:
         ref, _ = wkv_ref(r, k, v, w, u)
     _kernel("rwkv6_wkv", wkv_op, (r, k, v, w, u), {"chunk": chunk}, out,
             ref)
-    del r, k, v, w, u, out, ref
-
-    n = shp["reduce_slots"]
-    g = normal((n, layer_payload(get_config(TRAIN["arch"]))))
-    wts = normal((n,))
-    out = coded_reduce_op(g, wts)
-    with hp:
-        ref = coded_reduce_ref(g, wts)
-    _kernel("coded_reduce", coded_reduce_op, (g, wts), {}, out, ref)
 
 
 # --------------------------------------------------------------------- #

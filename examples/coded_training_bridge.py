@@ -1,12 +1,12 @@
 """Coded training bridge walkthrough: a real model through the co-sim.
 
 Trains a tiny transformer under every coding scheme with the full bridge
-(DESIGN.md §3.10): per-shard backward passes, the *measured* gradient
-payload drained through the Lyapunov uplink, worker uploads encoded with
-the epoch's effective coding matrix, decode through the ``coded_reduce``
-Pallas kernel, and the paper's no-op step when decode fails — then
-prints the loss-vs-simulated-wall-clock view and per-scheme
-time-to-target, the paper's headline comparison.
+(DESIGN.md §3.10): the *measured* gradient payload drained through the
+Lyapunov uplink, one coded step whose slot losses are weighted by the
+epoch's decode weights (its gradient is the decoded full gradient), and
+the paper's no-op step when decode fails — then prints the
+loss-vs-simulated-wall-clock view and per-scheme time-to-target, the
+paper's headline comparison.
 
     PYTHONPATH=src python examples/coded_training_bridge.py
     PYTHONPATH=src python examples/coded_training_bridge.py \
@@ -23,7 +23,7 @@ def main() -> None:
 
     from repro.configs.base import ModelConfig
     from repro.data.pipeline import SyntheticLMDataset
-    from repro.models.transformer import init_params, loss_fn
+    from repro.models.transformer import init_params
     from repro.optim.optimizers import adamw
     from repro.sim import available_scenarios, scenario_spec
     from repro.sim.cluster import SCHEMES
@@ -48,17 +48,15 @@ def main() -> None:
     dataset = SyntheticLMDataset(K=spec.K, examples_per_partition=2,
                                  seq_len=32, vocab=cfg.vocab, seed=0)
     params0 = init_params(cfg, jax.random.PRNGKey(0))
-    grad_fn = jax.jit(jax.value_and_grad(
-        lambda p, batch: loss_fn(p, batch, cfg)))
+    optimizer = adamw(1e-2)      # one object: one shared compiled step
 
     print(f"== coded training bridge on {args.scenario} ==")
     trainers = {}
     for scheme in SCHEMES:
         rec = (FleetRecorder(scenario=args.scenario, scheme=scheme)
                if args.trace and scheme == "two-stage" else None)
-        tr = CodedTrainer(cfg, spec, scheme, dataset, adamw(1e-2),
-                          params=params0, seed=args.seed, grad_fn=grad_fn,
-                          telemetry=rec)
+        tr = CodedTrainer(cfg, spec, scheme, dataset, optimizer,
+                          params=params0, seed=args.seed, telemetry=rec)
         tr.run(args.epochs)
         trainers[scheme] = tr
     first = trainers[SCHEMES[0]]
@@ -88,9 +86,8 @@ def main() -> None:
     if args.trace:
         path = write_chrome_trace(trainers["two-stage"].telemetry,
                                   args.trace)
-        print(f"\nwrote {path} — bridge phases (shard_grads/encode/"
-              f"decode_reduce/optimizer_step) alongside the engine's "
-              f"compute/comm/decode spans")
+        print(f"\nwrote {path} — bridge phases (slot_batch/coded_step) "
+              f"alongside the engine's compute/comm/decode spans")
 
 
 if __name__ == "__main__":

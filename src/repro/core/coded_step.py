@@ -12,7 +12,8 @@ TPU-native statement of TSDCFL (DESIGN.md §2):
 So the coded step costs ZERO extra collectives versus plain data-parallel
 SGD, and the straggler pattern enters as runtime data (weights), never as a
 recompile.  The host-side TwoStageRuntime (core/runtime.py) builds the slot
-assignment + weights each epoch.
+assignment + weights each epoch, and ``slot_batch`` lays the epoch's data
+partitions out in that assignment.
 
 Zero-weight slots are skipped when they can be.  Padding slots and every
 slot of a worker the decode discards carry weight exactly 0, so they add
@@ -40,8 +41,11 @@ import numpy as np
 from jax import lax
 from jax.experimental.layout import Layout, with_layout_constraint
 
-__all__ = ["SlotPlan", "build_slot_plan", "slot_weights", "computed_rows",
-           "make_train_step", "coded_value_and_grad", "make_coded_train_step"]
+from repro.telemetry.annotation import annotate
+
+__all__ = ["SlotPlan", "build_slot_plan", "slot_weights", "slot_batch",
+           "computed_rows", "make_train_step", "coded_value_and_grad",
+           "make_coded_train_step"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -89,6 +93,29 @@ def slot_weights(plan: SlotPlan, decode_w: np.ndarray) -> np.ndarray:
     w = plan.slot_coeff * decode_w[:, None]
     w[plan.slot_partition < 0] = 0.0
     return w
+
+
+def slot_batch(ds, plan, step: int) -> dict:
+    """Lay the epoch's partitions out in the plan's (M, n_slots, b, S)
+    layout; unused slots (partition -1) get zeros, and zero weight.
+
+    Profiler spans: ``coded.batch.data`` (the plan's distinct partitions,
+    drawn on the host in one ``ds.host_partitions`` call),
+    ``coded.batch.layout`` (one gather per key into the slot layout,
+    padding slots reading an appended zero row) and ``coded.batch.h2d``
+    (the slot batch to the device, one transfer).
+    """
+    sp = plan.slot_partition
+    ks = np.unique(sp[sp >= 0])
+    with annotate("coded.batch.data", partitions=len(ks)):
+        parts = ds.host_partitions(step, ks)
+    with annotate("coded.batch.layout"):
+        idx = np.where(sp >= 0, np.searchsorted(ks, sp), len(ks))
+        host = {key: np.concatenate(
+            [v, np.zeros((1,) + v.shape[1:], v.dtype)])[idx]
+            for key, v in parts.items()}
+    with annotate("coded.batch.h2d"):
+        return jax.device_put(host)
 
 
 # --------------------------------------------------------------------- #

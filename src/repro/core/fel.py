@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.coded_step import (build_slot_plan, make_coded_train_step,
-                                   slot_weights)
+                                   slot_batch, slot_weights)
 from repro.core.coding import CodingScheme
 from repro.core.runtime import (build_epoch_backend,
                                 simulate_epoch_single_stage)
@@ -126,29 +126,6 @@ class FELTrainer:
                                 else straggler_slow),
                 seed=seed, n_slots=n_slots)
 
-    # ------------------------------------------------------------------ #
-    def _slot_batch(self, epoch: int, plan) -> dict:
-        sample = self.dataset.partition(epoch, 0)
-        zeros = {k: np.zeros_like(np.asarray(v)) for k, v in sample.items()}
-        cache = {0: sample}
-
-        def part(k):
-            if k not in cache:
-                cache[k] = self.dataset.partition(epoch, k)
-            return cache[k]
-
-        out = {key: [] for key in sample}
-        for m in range(plan.M):
-            row = {key: [] for key in sample}
-            for s_ in range(plan.n_slots):
-                k = int(plan.slot_partition[m, s_])
-                src = part(k) if k >= 0 else zeros
-                for key in sample:
-                    row[key].append(np.asarray(src[key]))
-            for key in sample:
-                out[key].append(np.stack(row[key]))
-        return {key: jnp.asarray(np.stack(v)) for key, v in out.items()}
-
     def run_epoch(self, epoch: int) -> EpochLog:
         compute_t = comm_t = 0.0
         decode_ok = True
@@ -174,7 +151,7 @@ class FELTrainer:
             red = sim["redundancy"]
             eff = min(self.K / max(sim["executed_tasks"], 1e-12), 1.0)
             compute_t, decode_ok = time, sim["ok"]
-        batch = self._slot_batch(epoch, plan)
+        batch = slot_batch(self.dataset, plan, epoch)
         self.params, self.opt_state, aux = self.step_fn(
             self.params, self.opt_state, batch, jnp.asarray(w, jnp.float32))
         # failed decode ⟹ all-zero weights ⟹ aux['loss'] is a meaningless

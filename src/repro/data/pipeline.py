@@ -44,6 +44,15 @@ class PartitionedDataset:
     def partition(self, epoch: int, k: int):
         raise NotImplementedError
 
+    def host_partitions(self, epoch: int, ks) -> dict:
+        """Partitions ``ks`` of ``epoch`` as host arrays, stacked in the
+        order given: each key ``(len(ks), ...)``, one :meth:`partition`
+        per key (no ``ks``: empty arrays of partition 0's shapes)."""
+        ks = [int(k) for k in ks]
+        parts = [self.partition(epoch, k) for k in ks or [0]]
+        return {key: np.stack([np.asarray(p[key]) for p in parts])[:len(ks)]
+                for key in parts[0]}
+
 
 class SyntheticLMDataset(PartitionedDataset):
     """Procedural token sequences with learnable structure.

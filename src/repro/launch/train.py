@@ -26,12 +26,17 @@ import numpy as np
 
 from repro.checkpoint import Checkpointer
 from repro.configs.base import ModelConfig, get_config
+# slot_batch and per_slot_lm_loss live with the step and the model; they
+# are bound here, and train_coded reads slot_batch, coded_runtime and
+# coded_step_fn through this module's globals, because the benchmark
+# (chipbench/) patches and imports these names here
 from repro.core.coded_step import (computed_rows, make_coded_train_step,
-                                   make_train_step)
+                                   make_train_step, slot_batch)
 from repro.core.runtime import EpochResult, TwoStageRuntime
 from repro.data.pipeline import SyntheticLMDataset
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tfm
+from repro.models.transformer import per_slot_lm_loss
 from repro.optim import adamw
 from repro.telemetry.annotation import annotate
 
@@ -53,29 +58,6 @@ def _config(args) -> ModelConfig:
     return cfg
 
 
-def per_slot_lm_loss(cfg: ModelConfig):
-    """(params, slot_batch) -> (M, n_slots) mean next-token CE per slot."""
-    def fn(params, slot_batch):
-        toks = slot_batch["tokens"]          # (M, n_slots, b, S)
-        labs = slot_batch["labels"]
-        w = slot_batch["weights"]            # (M, n_slots, b, S)
-        M_, K_, b, S = toks.shape
-        batch = {"tokens": toks.reshape(M_ * K_ * b, S),
-                 "labels": labs.reshape(M_ * K_ * b, S),
-                 "weights": jnp.ones((M_ * K_ * b, S), jnp.float32)}
-        x, aux, _ = tfm.forward(params, batch, cfg)
-        head = (params["embed"].T if cfg.tie_embeddings
-                else params["lm_head"]).astype(x.dtype)
-        logits = (x @ head).astype(jnp.float32)
-        ll = jax.nn.log_softmax(logits)
-        ce = -jnp.take_along_axis(ll, batch["labels"][..., None],
-                                  axis=-1)[..., 0]
-        ce = (ce * w.reshape(M_ * K_ * b, S)).sum(-1) \
-            / jnp.maximum(w.reshape(M_ * K_ * b, S).sum(-1), 1e-9)
-        return ce.reshape(M_, K_, b).mean(-1)
-    return fn
-
-
 def coded_runtime(workers: int, *, straggler_prob: float = 0.2,
                   n_slots: int = 0, seed: int = 0) -> TwoStageRuntime:
     """The coded path's epoch planner: ``workers`` heterogeneous simulated
@@ -86,29 +68,6 @@ def coded_runtime(workers: int, *, straggler_prob: float = 0.2,
                            rates=np.linspace(1.0, 4.0, workers),
                            straggler_prob=straggler_prob, n_slots=n_slots,
                            seed=seed)
-
-
-def slot_batch(ds, plan, step: int) -> dict:
-    """Lay the epoch's partitions out in the plan's (M, n_slots, b, S)
-    layout; unused slots (partition -1) get zeros, and zero weight.
-
-    Profiler spans: ``coded.batch.data`` (the plan's distinct partitions,
-    drawn on the host in one :meth:`~SyntheticLMDataset.host_partitions`
-    call), ``coded.batch.layout`` (one gather per key into the slot
-    layout, padding slots reading an appended zero row) and
-    ``coded.batch.h2d`` (the slot batch to the device, one transfer).
-    """
-    sp = plan.slot_partition
-    ks = np.unique(sp[sp >= 0])
-    with annotate("coded.batch.data", partitions=len(ks)):
-        parts = ds.host_partitions(step, ks)
-    with annotate("coded.batch.layout"):
-        idx = np.where(sp >= 0, np.searchsorted(ks, sp), len(ks))
-        host = {key: np.concatenate(
-            [v, np.zeros((1,) + v.shape[1:], v.dtype)])[idx]
-            for key, v in parts.items()}
-    with annotate("coded.batch.h2d"):
-        return jax.device_put(host)
 
 
 def slot_counts(plan, weights, batch_shape) -> dict:

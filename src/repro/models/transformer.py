@@ -8,8 +8,8 @@ so the HLO stays small for 95-layer models and compile time is bounded.
 
 Entry points:
   init_params / param_axes           — materialize params / logical axes
-  loss_fn(params, batch, cfg, ...)   — training loss (per-position weights,
-                                       the hook used by the coded step)
+  loss_fn(params, batch, cfg, ...)   — training loss (per-position weights)
+  per_slot_lm_loss(cfg)              — per-slot losses for the coded step
   prefill(params, batch, cfg)        — forward + build decode cache
   decode_step(params, batch, cfg)    — one-token serve step with cache
   init_cache(cfg, batch, cap)        — empty cache pytree
@@ -35,8 +35,8 @@ from repro.models.settings import (constrain_activations,
                                    scan_maybe_unrolled)
 
 __all__ = ["GroupDef", "group_layout", "model_specs", "init_params",
-           "param_axes", "loss_fn", "prefill", "decode_step", "init_cache",
-           "forward"]
+           "param_axes", "loss_fn", "per_slot_lm_loss", "prefill",
+           "decode_step", "init_cache", "forward"]
 
 
 # ===================================================================== #
@@ -557,6 +557,29 @@ def loss_fn(params, batch, cfg: ModelConfig, *, dp_shards: int = 1):
     head = constrain_head(head)   # hoist the FSDP gather out of CE chunks
     loss = chunked_ce(x, head, batch["labels"], batch["weights"], cfg)
     return loss + 0.01 * aux
+
+
+def per_slot_lm_loss(cfg: ModelConfig):
+    """(params, slot_batch) -> (M, n_slots) mean next-token CE per slot,
+    the per-slot loss of the coded step (``core/coded_step.py``)."""
+    def fn(params, slot_batch):
+        toks = slot_batch["tokens"]          # (M, n_slots, b, S)
+        labs = slot_batch["labels"]
+        w = slot_batch["weights"]            # (M, n_slots, b, S)
+        M_, K_, b, S = toks.shape
+        batch = {"tokens": toks.reshape(M_ * K_ * b, S),
+                 "labels": labs.reshape(M_ * K_ * b, S),
+                 "weights": jnp.ones((M_ * K_ * b, S), jnp.float32)}
+        x, aux, _ = forward(params, batch, cfg)
+        head = _lm_head(params, cfg).astype(x.dtype)
+        logits = (x @ head).astype(jnp.float32)
+        ll = jax.nn.log_softmax(logits)
+        ce = -jnp.take_along_axis(ll, batch["labels"][..., None],
+                                  axis=-1)[..., 0]
+        ce = (ce * w.reshape(M_ * K_ * b, S)).sum(-1) \
+            / jnp.maximum(w.reshape(M_ * K_ * b, S).sum(-1), 1e-9)
+        return ce.reshape(M_, K_, b).mean(-1)
+    return fn
 
 
 # ---------------------------------------------------------------------- #

@@ -1,11 +1,12 @@
 """Coded-training bridge: real-model partial gradients through the co-sim.
 
 The first vertical slice connecting the two halves of the repo (DESIGN.md
-§3.10): per-shard gradients of a real jax model (``repro.models``) flow
-through the closed-loop edge co-simulator (``repro.sim``) under the
-paper's coding schemes, are decoded by the ``coded_reduce`` Pallas kernel
-and produce loss-vs-simulated-wall-clock curves per scheme — the paper's
-headline Fig 5e/6e claim, end-to-end.
+§3.10): a real jax model (``repro.models``) trains through the
+closed-loop edge co-simulator (``repro.sim``) under the paper's coding
+schemes.  Each epoch's decode weights drive the one coded train step
+(``repro.core.coded_step``), whose gradient is the decoded full gradient,
+and the run yields loss-vs-simulated-wall-clock curves per scheme — the
+paper's headline Fig 5e/6e claim, end-to-end.
 """
 from repro.train.coded_trainer import CodedTrainer, TrainEpochLog
 from repro.train.curves import (curve_dict, loss_curve, running_best,

@@ -2,50 +2,43 @@
 
 Per epoch (DESIGN.md §3.10):
 
-  1. **shard gradients** — one backward pass per data shard k of the real
-     model (``loss_fn(params, D_k)``), stacked into ``G ∈ (K, D)`` f32;
-  2. **co-sim epoch** — ``EdgeCluster.run_epoch`` samples the compute
+  1. **co-sim epoch** — ``EdgeCluster.run_epoch`` samples the compute
      phase and drains each worker's *measured* payload (the flattened
      gradient's size, not the synthetic constant) through the Lyapunov
      scheduler; decode is gated on byte arrival;
-  3. **encode** — worker uploads ``ĝ_m = Σ_k B_eff[m,k]·g_k`` where
-     ``B_eff`` is the epoch's effective coding matrix read off the slot
-     plan (stage-1 + stage-2 rows for two-stage);
-  4. **decode** — the engine's ``(M, n_slots)`` weight matrix factors as
-     ``w[m,s] = a_m·coeff[m,s]`` (``slot_weights`` construction), so the
-     per-worker decode weights ``a`` — produced by ``rs_decode_weights``/
-     ``decode_weights`` inside the engine — are recovered exactly and the
-     arrived uploads are reduced by the ``coded_reduce`` Pallas kernel:
-     ``Σ_m a_m ĝ_m = Σ_k g_k``, the exact full-batch gradient;
-  5. **step** — one optimizer update on the decoded gradient, or the
-     paper's *no-op step* when decode failed: params and optimizer state
-     are left untouched (bit-identical), the epoch burned wall-clock only.
+  2. **coded step** — on a successful decode, the epoch's partitions are
+     laid out in its slot plan (``slot_batch``) and one jitted
+     ``make_coded_train_step`` runs one backward pass over the slot
+     losses weighted by the engine's ``(M, n_slots)`` weights
+     ``a_m·coeff[m,s]``.  By linearity its gradient is the decoded
+     ``Σ_m a_m ĝ_m = Σ_k g_k``, the exact full-batch gradient, and one
+     optimizer update follows;
+  3. **no-op step** — when decode failed, the paper's no-op: params and
+     optimizer state are left untouched (bit-identical), the epoch burned
+     wall-clock only.
 
 Wall-clock attribution: *simulated* time comes from the co-sim
 (``compute_time``/``comm_time``); *host* time for the bridge's own work
-is recorded as telemetry phase spans (``shard_grads`` / ``encode`` /
-``decode_reduce`` / ``optimizer_step``) on the same recorder the cluster
-threads its compute/comm/decode spans through.
+is recorded as telemetry phase spans (``slot_batch`` / ``coded_step``)
+on the same recorder the cluster threads its compute/comm/decode spans
+through.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, List, Optional
+import functools
+from typing import Any, List, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from repro.core.runtime import EpochResult
-from repro.kernels.coded_reduce.ops import coded_reduce_op
-from repro.models.transformer import init_params, loss_fn as model_loss_fn
+from repro.core.coded_step import make_coded_train_step, slot_batch
+from repro.models.transformer import init_params, per_slot_lm_loss
 from repro.sim.spec import ScenarioSpec, build_cluster
 from repro.telemetry.recorder import FleetRecorder, phase_span
-from repro.train.partition import (DEFAULT_BYTES_PER_UNIT, GradPartition,
-                                   flatten_grads)
+from repro.train.partition import DEFAULT_BYTES_PER_UNIT, GradPartition
 
-__all__ = ["CodedTrainer", "TrainEpochLog", "decode_weights_from_result",
-           "effective_code_matrix"]
+__all__ = ["CodedTrainer", "TrainEpochLog"]
 
 
 @dataclasses.dataclass
@@ -61,34 +54,12 @@ class TrainEpochLog:
     grad_bytes: float           # measured payload (scenario units)
 
 
-def effective_code_matrix(result: EpochResult, K: int) -> np.ndarray:
-    """The epoch's effective ``(M, K)`` coding matrix off the slot plan:
-    ``B_eff[m,k] = Σ_s coeff[m,s]·[slot_partition[m,s] == k]`` — for
-    static schemes this is exactly ``scheme.B`` (rows on global worker
-    ids); for two-stage it stacks the stage-1 and stage-2 rows the
-    runtime packed for this epoch."""
-    plan = result.plan
-    part, coeff = plan.slot_partition, plan.slot_coeff
-    B = np.zeros((plan.M, K))
-    m_idx, s_idx = np.nonzero((part >= 0) & (coeff != 0.0))
-    np.add.at(B, (m_idx, part[m_idx, s_idx]), coeff[m_idx, s_idx])
-    return B
-
-
-def decode_weights_from_result(result: EpochResult) -> np.ndarray:
-    """Per-worker decode weights ``a`` recovered from the engine's slot
-    weight matrix.  ``slot_weights`` builds ``w[m,s] = a_m·coeff[m,s]``,
-    so ``a_m = w[m,s*]/coeff[m,s*]`` at any slot with a nonzero
-    coefficient — zero for workers that contribute nothing (stragglers,
-    non-selected, failed decode)."""
-    plan, w = result.plan, np.asarray(result.weights, np.float64)
-    part, coeff = plan.slot_partition, plan.slot_coeff
-    a = np.zeros(plan.M)
-    for m in range(plan.M):
-        live = np.flatnonzero((part[m] >= 0) & (coeff[m] != 0.0))
-        if live.size:
-            a[m] = w[m, live[0]] / coeff[m, live[0]]
-    return a
+@functools.lru_cache(maxsize=8)
+def _coded_step(cfg, optimizer):
+    """The jitted coded step for ``cfg`` and one optimizer object, shared
+    by every trainer given both, so a scheme × seed sweep compiles once
+    per slot shape.  Nothing is donated: trainers may share ``params``."""
+    return jax.jit(make_coded_train_step(per_slot_lm_loss(cfg), optimizer))
 
 
 class CodedTrainer:
@@ -106,9 +77,7 @@ class CodedTrainer:
     def __init__(self, cfg, spec: ScenarioSpec, scheme: str, dataset,
                  optimizer, *, params: Optional[Any] = None, seed: int = 0,
                  bytes_per_unit: float = DEFAULT_BYTES_PER_UNIT,
-                 telemetry: Optional[FleetRecorder] = None,
-                 loss_fn: Optional[Callable] = None,
-                 grad_fn: Optional[Callable] = None):
+                 telemetry: Optional[FleetRecorder] = None):
         if dataset.K != spec.K:
             raise ValueError(f"dataset has K={dataset.K} partitions, "
                              f"scenario wants K={spec.K}")
@@ -131,72 +100,28 @@ class CodedTrainer:
         if telemetry is not None:
             self.cluster.telemetry = telemetry
 
-        if grad_fn is not None:
-            # pre-built ``(params, batch) -> (loss, grads)`` — lets a
-            # benchmark comparing many (scheme × seed) trainers share one
-            # compiled backward pass instead of re-jitting per trainer
-            self._shard_grad = grad_fn
-        else:
-            base_loss = loss_fn if loss_fn is not None else (
-                lambda p, batch: model_loss_fn(p, batch, cfg))
-            # one compile: every shard has identical batch shapes
-            self._shard_grad = jax.jit(jax.value_and_grad(base_loss))
-        self._update = jax.jit(optimizer.update)
+        self._step = _coded_step(cfg, optimizer)
         self.logs: List[TrainEpochLog] = []
         self.noop_steps = 0
-        # test/debug introspection: last epoch's decoded gradient and the
-        # uncoded full-batch reference it must match when decode succeeds
-        self.last_decoded: Optional[np.ndarray] = None
-        self.last_full_grad: Optional[np.ndarray] = None
 
-    # ------------------------------------------------------------------ #
-    def shard_gradients(self, epoch: int):
-        """``(losses (K,), G (K, D) f32)`` — one backward per data shard."""
-        losses, rows = [], []
-        for k in range(self.dataset.K):
-            loss, grads = self._shard_grad(
-                self.params, self.dataset.partition(epoch, k))
-            losses.append(loss)
-            rows.append(flatten_grads(grads))
-        return jnp.stack(losses), jnp.stack(rows)
-
-    def _encode(self, result: EpochResult, G: jnp.ndarray):
-        """Worker-side encode: uploads of the contributing workers
-        (rows of the epoch's effective code matrix applied to the shard
-        gradients) plus their engine-recovered decode weights."""
-        B_eff = effective_code_matrix(result, self.dataset.K)
-        a = decode_weights_from_result(result)
-        contrib = np.flatnonzero(a != 0.0)
-        uploads = jnp.asarray(B_eff[contrib], jnp.float32) @ G
-        return uploads, jnp.asarray(a[contrib], jnp.float32)
-
-    # ------------------------------------------------------------------ #
     def run_epoch(self, epoch: int) -> TrainEpochLog:
         rec = self.telemetry
-        with phase_span(rec, "shard_grads", epoch=epoch):
-            losses, G = self.shard_gradients(epoch)
         # the co-sim epoch always runs (it owns the per-seed RNG stream),
-        # whether or not the decode below ends up succeeding
+        # whether or not the decode ends up succeeding
         result = self.cluster.run_epoch(epoch)
         if result.decode_ok:
-            with phase_span(rec, "encode", epoch=epoch):
-                uploads, a = self._encode(result, G)
-            with phase_span(rec, "decode_reduce", epoch=epoch):
-                decoded = coded_reduce_op(uploads, a)
-                self.last_decoded = np.asarray(decoded)
-                self.last_full_grad = np.asarray(G.sum(axis=0))
-            with phase_span(rec, "optimizer_step", epoch=epoch):
-                self.params, self.opt_state = self._update(
-                    self.partition.unflatten(decoded), self.opt_state,
-                    self.params)
-            loss = float(losses.sum())
+            with phase_span(rec, "slot_batch", epoch=epoch):
+                batch = slot_batch(self.dataset, result.plan, epoch)
+            with phase_span(rec, "coded_step", epoch=epoch):
+                self.params, self.opt_state, aux = self._step(
+                    self.params, self.opt_state, batch,
+                    jnp.asarray(result.weights, jnp.float32))
+                loss = float(aux["loss"])
         else:
             # the paper's no-op step: params and optimizer state are the
             # same objects — bit-identical, nothing was applied.  Loss is
             # NaN so curves show a gap, not a dip (fel.py convention).
             self.noop_steps += 1
-            self.last_decoded = None
-            self.last_full_grad = np.asarray(G.sum(axis=0))
             loss = float("nan")
         log = TrainEpochLog(
             epoch=epoch, loss=loss, time=float(result.time),

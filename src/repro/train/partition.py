@@ -3,11 +3,12 @@
 The paper codes over K *data* shards: worker m's upload is the coded
 combination ĝ_m = Σ_k B[m,k]·g_k of per-shard partial gradients, each the
 gradient of the loss over data partition D_k.  This module supplies the
-three pieces the bridge needs to run a *real* model through that pipeline:
+payload side of running a *real* model through that pipeline:
 
   * :func:`flatten_grads` / :class:`GradPartition` — a gradient pytree
-    flattened to one ``(D,)`` f32 payload vector and back, so worker
-    uploads are plain rows a Pallas kernel can reduce;
+    flattened to one ``(D,)`` f32 payload vector and back; the bridge
+    reads its upload size off it (the coded step itself works on
+    pytrees, ``repro.core.coded_step``);
   * :func:`shard_assignment` — which data shards each worker computes,
     read off the coding matrix ``B`` (``CodingScheme.support``);
   * :func:`payload_units` — the *measured* per-upload payload, derived
@@ -71,11 +72,9 @@ def payload_units(n_bytes: float,
 class GradPartition:
     """Flattening contract for one model's gradients.
 
-    Captured once from a parameter template; every per-shard gradient of
-    the same model flattens to the same ``(D,)`` layout, so shard
-    gradients stack into the ``(K, D)`` matrix the coded pipeline
-    multiplies with ``B`` and the decode kernel reduces.  ``unflatten``
-    is the exact inverse (the optimizer consumes pytrees).
+    Captured once from a parameter template; every gradient of the same
+    model flattens to the same ``(D,)`` layout, one upload of
+    ``payload_bytes``.  ``unflatten`` is the exact inverse.
     """
     D: int                                 # flattened gradient length
     payload_bytes: float                   # one upload's size in bytes

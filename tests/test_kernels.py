@@ -15,8 +15,6 @@ from repro.kernels.rglru_scan.ops import rglru_scan_op
 from repro.kernels.rglru_scan.ref import rglru_ref
 from repro.kernels.rwkv6_wkv.ops import wkv_op
 from repro.kernels.rwkv6_wkv.ref import wkv_ref
-from repro.kernels.coded_reduce.ops import coded_reduce_op
-from repro.kernels.coded_reduce.ref import coded_reduce_ref
 
 
 def _tol(dtype):
@@ -150,92 +148,3 @@ def test_wkv_bf16_inputs():
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                rtol=5e-2, atol=5e-2)
-
-
-# --------------------------------------------------------------------- #
-# coded decode-reduce
-# --------------------------------------------------------------------- #
-@pytest.mark.parametrize("n_slots,D", [(4, 512), (7, 1024), (16, 2048)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_coded_reduce_sweep(n_slots, D, dtype):
-    rng = np.random.default_rng(7)
-    g = jnp.asarray(rng.standard_normal((n_slots, D)), dtype)
-    w = jnp.asarray(rng.standard_normal((n_slots,)), jnp.float32)
-    out = coded_reduce_op(g, w, block_d=256, interpret=True)
-    ref = coded_reduce_ref(g, w)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-2 if dtype == jnp.bfloat16 else 1e-5,
-                               atol=1e-2 if dtype == jnp.bfloat16 else 1e-5)
-
-
-def test_coded_reduce_is_exact_decode():
-    """Kernel composes with coding matrices: decode(coded) == sum."""
-    from repro.core.coding import cyclic_repetition, decode_weights
-    rng = np.random.default_rng(8)
-    M, s, D = 6, 2, 512
-    scheme = cyclic_repetition(M, s)
-    g_parts = rng.standard_normal((M, D)).astype(np.float32)   # g_k
-    coded = jnp.asarray(scheme.B @ g_parts, jnp.float32)       # per worker
-    alive = np.ones(M, bool)
-    alive[[1, 4]] = False
-    a = decode_weights(scheme, alive)
-    out = coded_reduce_op(coded, jnp.asarray(a, jnp.float32),
-                          block_d=256, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), g_parts.sum(0), rtol=1e-4,
-                               atol=1e-4)
-
-
-@pytest.mark.parametrize("D", [513, 777, 2047])
-def test_coded_reduce_non_multiple_block_d(D):
-    """Arbitrary payload dims: the kernel zero-pads D up to a block_d
-    multiple internally, so real flattened-gradient sizes (never a tidy
-    power of two) run without caller-side padding."""
-    rng = np.random.default_rng(10)
-    g = jnp.asarray(rng.standard_normal((5, D)), jnp.float32)
-    w = jnp.asarray(rng.standard_normal((5,)), jnp.float32)
-    out = coded_reduce_op(g, w, block_d=512, interpret=True)
-    assert out.shape == (D,)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(coded_reduce_ref(g, w)),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_coded_reduce_rs_decode_weights_erasure_sweep():
-    """Kernel under *realistic* decode weights: every ≤s straggler-erasure
-    pattern of a CRS(M, s) code, decoded with ``rs_decode_weights`` exactly
-    as the runtime does, recovers the exact shard sum — feeding the kernel
-    only the surviving rows, the shape the training bridge produces."""
-    from itertools import combinations
-    from repro.core.coding import cyclic_repetition, rs_decode_weights
-    rng = np.random.default_rng(11)
-    M, s, D = 6, 2, 700                    # D not a block_d multiple
-    scheme = cyclic_repetition(M, s)
-    g_parts = rng.standard_normal((M, D)).astype(np.float32)
-    coded = np.asarray(scheme.B @ g_parts, np.float32)
-    patterns = [()] + [(i,) for i in range(M)] + \
-        list(combinations(range(M), s))
-    for dead in patterns:
-        alive = np.ones(M, bool)
-        alive[list(dead)] = False
-        a = rs_decode_weights(scheme.nodes, alive, scheme.s)
-        contrib = np.flatnonzero(a != 0.0)   # bridge passes only a≠0 rows
-        out = coded_reduce_op(jnp.asarray(coded[contrib]),
-                              jnp.asarray(a[contrib], jnp.float32),
-                              block_d=256, interpret=True)
-        np.testing.assert_allclose(np.asarray(out), g_parts.sum(0),
-                                   rtol=1e-3, atol=1e-3,
-                                   err_msg=f"dead={dead}")
-
-
-def test_coded_reduce_bridge_payload_shape():
-    """Kernel vs ref on a bridge-sized payload: K=6 shards of a ~100k-dim
-    flattened gradient (the train-e2e TINY model scale), default block."""
-    rng = np.random.default_rng(12)
-    n_slots, D = 6, 98624
-    g = jnp.asarray(rng.standard_normal((n_slots, D)) * 0.1, jnp.float32)
-    w = jnp.asarray(rng.standard_normal((n_slots,)), jnp.float32)
-    out = coded_reduce_op(g, w, interpret=True)
-    assert out.shape == (D,) and out.dtype == jnp.float32
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(coded_reduce_ref(g, w)),
-                               rtol=1e-4, atol=1e-4)

@@ -1,4 +1,4 @@
-"""The four Pallas kernels compile for a TPU v5e at real model widths.
+"""The three Pallas kernels compile for a TPU v5e at real model widths.
 
 No chip is needed: the TPU compiler compiles for a topology that is
 described, not attached, and refuses what the chip's compiler would refuse
@@ -8,30 +8,18 @@ described inside a module fixture, so only the worker that runs this file
 loads the TPU library; the persistent compile cache is off around the
 compiles, since a described device cannot read its entries back.
 """
-import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.configs.base import get_config
-from repro.kernels.coded_reduce.coded_reduce import coded_reduce_pallas
 from repro.kernels.flash_attention.flash_attention import \
     flash_attention_pallas
 from repro.kernels.rglru_scan.rglru_scan import rglru_scan_pallas
 from repro.kernels.rwkv6_wkv.rwkv6_wkv import wkv_pallas
-from repro.models import transformer as tfm
 
 HBM_BYTES = 16 * 2 ** 30
-
-
-def _layer_payload(arch: str) -> int:
-    """Parameters in one layer of ``arch`` — one decode-reduce payload."""
-    cfg = dataclasses.replace(get_config(arch), n_layers=1)
-    shapes = jax.eval_shape(lambda: tfm.init_params(cfg,
-                                                    jax.random.PRNGKey(0)))
-    return sum(a.size for a in jax.tree.leaves(shapes["groups"]))
 
 
 #: kernel -> (function, argument shapes and dtypes at real widths)
@@ -45,8 +33,6 @@ CASES = {
     "rwkv6_wkv": (functools.partial(wkv_pallas, chunk=64),
                   [((1, 32, 2048, 64), jnp.float32)] * 4
                   + [((32, 64), jnp.float32)]),
-    # 6 slots of one stablelm-1.6b layer's gradient (filled in the test)
-    "coded_reduce": (coded_reduce_pallas, None),
 }
 
 
@@ -76,9 +62,6 @@ def one_chip():
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(name, one_chip):
     fn, specs = CASES[name]
-    if specs is None:
-        specs = [((6, _layer_payload("stablelm-1.6b")), jnp.float32),
-                 ((6,), jnp.float32)]
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
             for shape, dtype in specs]
     compiled = jax.jit(fn).lower(*args).compile()

@@ -1,14 +1,15 @@
 """Coded training bridge acceptance tests (DESIGN.md §3.10).
 
-The ISSUE's contract, pinned per scheme:
+The bridge's contract, pinned per scheme:
 
-  * decode success ⟹ the decoded gradient equals the uncoded full-batch
-    gradient (sum of the per-shard partial gradients) to allclose;
+  * decode success ⟹ one optimizer step on the uncoded full-batch
+    gradient (the sum of the per-shard partial gradients), to allclose;
   * decode failure ⟹ the paper's *no-op step*: params and optimizer
     state are bit-identical to before the epoch;
   * the payload the co-sim drains is *measured* from the flattened
     gradient, not the scenario's synthetic ``grad_bytes`` constant.
 """
+import dataclasses
 import math
 
 import jax
@@ -17,6 +18,7 @@ import pytest
 
 from repro.configs.base import ModelConfig
 from repro.data.pipeline import SyntheticLMDataset
+from repro.models import transformer as tfm
 from repro.models.transformer import init_params
 from repro.optim.optimizers import adamw
 from repro.sim.cluster import SCHEMES
@@ -26,8 +28,6 @@ from repro.train import (CodedTrainer, GradPartition, TrainEpochLog,
                          curve_dict, flatten_grads, loss_curve,
                          payload_units, running_best, shard_assignment,
                          time_to_target)
-from repro.train.coded_trainer import (decode_weights_from_result,
-                                       effective_code_matrix)
 
 #: One-layer model: big enough to exercise a real pytree (~23k params),
 #: small enough that the 4-scheme sweep stays in CI smoke budget.
@@ -37,13 +37,15 @@ TINY = ModelConfig(
     d_ff=64, vocab=64, remat="none", compute_dtype="float32")
 
 SCENARIO = "bursty-stragglers"
+#: one optimizer object: the trainers below share one compiled step
+OPT = adamw(1e-2)
 
 
 def _trainer(scheme, *, seed=0, spec=None, telemetry=None):
     spec = spec if spec is not None else scenario_spec(SCENARIO)
     dataset = SyntheticLMDataset(K=spec.K, examples_per_partition=1,
                                  seq_len=16, vocab=TINY.vocab, seed=0)
-    return CodedTrainer(TINY, spec, scheme, dataset, adamw(1e-2),
+    return CodedTrainer(TINY, spec, scheme, dataset, OPT,
                         seed=seed, telemetry=telemetry)
 
 
@@ -113,21 +115,47 @@ def test_trainer_rejects_mismatched_dataset():
 # --------------------------------------------------------------------- #
 # the acceptance pin: decode success ⟹ exact full-batch gradient
 # --------------------------------------------------------------------- #
+def _recording_cluster(tr, monkeypatch) -> list:
+    """The trainer's epoch results, appended as its cluster returns them."""
+    results, run = [], tr.cluster.run_epoch
+    monkeypatch.setattr(tr.cluster, "run_epoch",
+                        lambda epoch: results.append(run(epoch))
+                        or results[-1])
+    return results
+
+
 @pytest.mark.parametrize("scheme", SCHEMES)
-def test_decoded_gradient_matches_uncoded_full_batch(scheme):
+def test_decoded_gradient_matches_uncoded_full_batch(scheme, monkeypatch):
     tr = _trainer(scheme)
+    results = _recording_cluster(tr, monkeypatch)
+    params0, opt0 = tr.params, tr.opt_state
     log = tr.run_epoch(0)
     assert log.decode_ok          # bursty-stragglers: slow, never dead
-    assert tr.last_decoded is not None
-    np.testing.assert_allclose(tr.last_decoded, tr.last_full_grad,
-                               rtol=2e-4, atol=2e-4)
-    # decode identity on the epoch's own plan: aᵀ·B_eff = 1ᵀ
-    result = tr.cluster.run_epoch(1)
-    if result.decode_ok:
-        B_eff = effective_code_matrix(result, tr.dataset.K)
-        a = decode_weights_from_result(result)
-        np.testing.assert_allclose(a @ B_eff, np.ones(tr.dataset.K),
-                                   rtol=1e-6, atol=1e-6)
+    # the gradient the step applied, off AdamW's first moment m = (1-b1)·g
+    assert int(tr.opt_state.step) == 1
+    decoded = jax.tree.map(lambda m: m / (1 - 0.9), tr.opt_state.m)
+    # the uncoded reference: Σ_k ∇ℓ_k, one backward per partition
+    grad = jax.jit(jax.grad(lambda p, batch: tfm.loss_fn(p, batch, TINY)))
+    full = jax.tree.map(lambda *g: sum(g), *[
+        grad(params0, tr.dataset.partition(0, k))
+        for k in range(tr.dataset.K)])
+    for got, ref in zip(jax.tree.leaves(decoded), jax.tree.leaves(full)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=2e-4, atol=2e-4)
+    # and the params are one step from params0 on it.  (Not on `full`:
+    # AdamW's first step is lr·g/(|g| + eps), so an element whose
+    # gradient is within rounding of 0 may move by up to 2·lr.)
+    want, _ = tr.optimizer.update(decoded, opt0, params0)
+    for got, ref in zip(jax.tree.leaves(tr.params), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=2e-4, atol=2e-4)
+    # decode identity on the epoch's own plan, read off the weights the
+    # step used: the slots holding partition k weigh 1 together (aᵀB = 1)
+    plan, w = results[0].plan, np.asarray(results[0].weights)
+    per_partition = [w[plan.slot_partition == k].sum()
+                     for k in range(tr.dataset.K)]
+    np.testing.assert_allclose(per_partition, np.ones(tr.dataset.K),
+                               rtol=1e-6, atol=1e-6)
 
 
 @pytest.mark.parametrize("scheme", SCHEMES)
@@ -143,14 +171,15 @@ def test_losses_identical_across_schemes_first_epoch(scheme):
 # --------------------------------------------------------------------- #
 # the acceptance pin: decode failure ⟹ bit-identical no-op step
 # --------------------------------------------------------------------- #
-def test_decode_failure_is_bit_identical_noop():
+@pytest.mark.parametrize("scheme", SCHEMES)
+def test_decode_failure_is_bit_identical_noop(scheme):
     spec = scenario_spec(SCENARIO).with_overrides(fault_prob=1.0)
-    tr = _trainer("two-stage", spec=spec)
+    tr = _trainer(scheme, spec=spec)
     params_before, opt_before = tr.params, tr.opt_state
     flat_before = np.asarray(flatten_grads(tr.params))
     log = tr.run_epoch(0)
     assert not log.decode_ok and math.isnan(log.loss)
-    assert tr.noop_steps == 1 and tr.last_decoded is None
+    assert tr.noop_steps == 1
     # the very same objects — nothing was applied, not even a copy
     assert tr.params is params_before
     assert tr.opt_state is opt_before
@@ -160,13 +189,41 @@ def test_decode_failure_is_bit_identical_noop():
     assert log.time > 0.0
 
 
-def test_successful_epoch_moves_params():
-    tr = _trainer("two-stage")
+@pytest.mark.parametrize("scheme", SCHEMES)
+def test_successful_epoch_moves_params(scheme):
+    tr = _trainer(scheme)
     flat_before = np.asarray(flatten_grads(tr.params))
     log = tr.run_epoch(0)
     assert log.decode_ok
     assert not np.array_equal(np.asarray(flatten_grads(tr.params)),
                               flat_before)
+
+
+def test_trainers_share_one_compiled_step(monkeypatch):
+    """Trainers with an equal config and the same optimizer object share
+    one jitted step: it compiles once per slot shape, not per trainer."""
+    import repro.train.coded_trainer as ct
+    cfg = dataclasses.replace(TINY, name="bridge-test-shared-step")
+    spec = scenario_spec(SCENARIO)
+    dataset = SyntheticLMDataset(K=spec.K, examples_per_partition=1,
+                                 seq_len=16, vocab=cfg.vocab, seed=0)
+    shapes, make = set(), ct.slot_batch
+
+    def slot_batch(ds, plan, epoch):
+        sb = make(ds, plan, epoch)
+        shapes.add(sb["tokens"].shape)
+        return sb
+
+    monkeypatch.setattr(ct, "slot_batch", slot_batch)
+    opt = adamw(1e-2)
+    a, b = (CodedTrainer(cfg, spec, scheme, dataset, opt, seed=seed)
+            for scheme, seed in (("two-stage", 0), ("cyclic", 1)))
+    assert a._step is b._step
+    a.run(2)
+    b.run(2)
+    assert shapes and a._step._cache_size() == len(shapes)
+    other = CodedTrainer(cfg, spec, "two-stage", dataset, adamw(1e-2))
+    assert other._step is not a._step
 
 
 # --------------------------------------------------------------------- #
@@ -177,8 +234,7 @@ def test_bridge_phases_recorded_as_spans():
     tr = _trainer("two-stage", telemetry=rec)
     tr.run(1)
     names = {s.name for s in rec.spans}
-    assert {"shard_grads", "encode", "decode_reduce",
-            "optimizer_step"} <= names
+    assert {"slot_batch", "coded_step"} <= names
     # the cluster threads its own phase spans through the same recorder
     assert {"compute_phase", "comm", "decode"} <= names
 
